@@ -24,10 +24,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import hnsw as HN
 from repro.core import ivf as IV
 from repro.core import pq as PQ
 from repro.data import synthetic as SY
+
+# every benchmark imports this module before it compiles anything
+compile_cache.enable()
 
 CACHE = os.environ.get("BENCH_CACHE", "artifacts/bench_cache")
 

@@ -14,7 +14,10 @@ figure's headline claim, print a ``SMOKE OK`` line and exit zero.
 
 Modules run as subprocesses: several check ``--smoke`` at import time
 to shrink env-derived constants, so in-process imports cannot apply
-the contract uniformly.
+the contract uniformly.  The kernel micro-benchmarks (``kernels.py``)
+run as a child too: this parent never starts a JAX backend, because on
+a TPU host the first process to do so holds the chip and its children
+could not open it.
 """
 from __future__ import annotations
 
@@ -24,10 +27,6 @@ import os
 import subprocess
 import sys
 import time
-
-import numpy as np
-import jax
-import jax.numpy as jnp
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 REPO_ROOT = os.path.dirname(BENCH_DIR)
@@ -59,47 +58,6 @@ def run_module(stem: str, smoke: bool) -> int:
     return subprocess.call(cmd, env=env, cwd=REPO_ROOT)
 
 
-def bench_kernels() -> None:
-    """Kernel micro-benchmarks (jnp ref path timing on CPU; the Pallas
-    kernels themselves are TPU-target and validated via interpret)."""
-    from repro.kernels import ops, ref
-    from benchmarks.common import time_fn
-    rng = np.random.default_rng(0)
-
-    q = jnp.asarray(rng.normal(size=(16, 64)).astype(np.float32))
-    c = jnp.asarray(rng.normal(size=(4096, 64)).astype(np.float32))
-    f = jax.jit(lambda q, c: ref.centroid_topk(q, c, 64))
-    t = time_fn(f, q, c)
-    print(f"kernel.centroid_topk_ref,{1e6*t:.1f},p=4096 d=64 b=16")
-
-    lv = jnp.asarray(rng.normal(size=(128, 256, 64)).astype(np.float32))
-    li = jnp.asarray(rng.integers(0, 10**6, (128, 256)).astype(np.int32))
-    sel = jnp.asarray(np.stack([rng.permutation(128)[:16]
-                                for _ in range(16)]).astype(np.int32))
-    f = jax.jit(lambda q, s: ref.ivf_scan_batch(q, lv, li, s, 10))
-    t = time_fn(f, q, sel)
-    print(f"kernel.ivf_scan_ref,{1e6*t:.1f},np=16 Lmax=256 b=16")
-
-    cf = jnp.asarray(rng.normal(size=(128, 64)).astype(np.float32))
-    f = jax.jit(lambda q: ops.fused_turn(q, cf, lv, li, nprobe=16, k=10,
-                                         mode="ref")[:2])
-    t = time_fn(f, q)
-    print(f"kernel.fused_turn_ref,{1e6*t:.1f},p=128 np=16 Lmax=256 b=16")
-
-    qa = jnp.asarray(rng.normal(size=(2, 8, 1024, 64)).astype(np.float32))
-    ka = jnp.asarray(rng.normal(size=(2, 2, 1024, 64)).astype(np.float32))
-    f = jax.jit(lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
-                                                    mode="ref"))
-    t = time_fn(f, qa, ka, ka)
-    print(f"kernel.attention_ref,{1e6*t:.1f},b2 h8 s1024 d64")
-
-    table = jnp.asarray(rng.normal(size=(100000, 32)).astype(np.float32))
-    ids = jnp.asarray(rng.integers(-1, 100000, (4096, 20)).astype(np.int32))
-    f = jax.jit(lambda t_, i_: ops.embedding_bag(t_, i_, mode="ref"))
-    t = time_fn(f, table, ids)
-    print(f"kernel.embedding_bag_ref,{1e6*t:.1f},V=1e5 b=4096 L=20")
-
-
 def main() -> None:
     modules = discover()
     shorts = sorted({m.split("_")[0] for m in modules})
@@ -126,13 +84,12 @@ def main() -> None:
                  f"(discovered: {', '.join(modules)})")
 
     t0 = time.time()
-    if args.only in ("all", "kernels"):
-        print("name,us_per_call,derived")
-        bench_kernels()
-    if args.only == "kernels":
-        return
-
     failed = []
+    if args.only in ("all", "kernels") and run_module("kernels", False):
+        failed.append("kernels")
+    if args.only == "kernels":
+        sys.exit(1 if failed else 0)
+
     for stem in selected:
         if run_module(stem, args.smoke) != 0:
             failed.append(stem)

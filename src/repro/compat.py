@@ -1,49 +1,11 @@
-"""Cross-version jax API shims.
+"""The one seam over ``jax.shard_map``.
 
-The container pins jax 0.4.37, where ``shard_map`` lives in
-``jax.experimental.shard_map`` (kwarg ``check_rep``) and ``jax.set_mesh``
-does not exist.  Newer jax promotes ``jax.shard_map`` (kwarg
-``check_vma``) and adds ``jax.set_mesh``.  Call sites import the two
-names from here so the code runs unmodified on either side of the
-rename.  (The Pallas-specific shim lives in ``repro.kernels.compat``.)
+Call sites spell ``compat.shard_map(...)`` rather than ``jax.shard_map``
+so that ``analysis.shard_specs`` can swap this module attribute for a
+recording wrapper and see every shard_map the sharded plugins build.
 """
 from __future__ import annotations
 
 import jax
 
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-    from jax._src.mesh import thread_resources as _thread_resources
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` signature, executable on jax 0.4.x.
-
-    ``check_vma`` maps onto the old ``check_rep``; ``mesh=None`` resolves
-    the active mesh context (``set_mesh`` below) as new jax does.
-    """
-    if _NEW_SHARD_MAP:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    if mesh is None:
-        mesh = _thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError(
-                "shard_map(mesh=None) needs an active mesh context "
-                "(enter repro.compat.set_mesh(mesh) first)")
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager equivalent of ``jax.set_mesh``.
-
-    Old jax: a ``Mesh`` is itself a context manager that installs the
-    physical mesh our ``shard_map`` shim resolves against.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+shard_map = jax.shard_map

@@ -54,6 +54,9 @@ from repro.core import hnsw as _hnsw
 from repro.core import ivf as _ivf
 from repro.core import toploc as _tl
 from repro.core.topk import intersect_count
+from repro.kernels import ops as _kops
+from repro.kernels import tiling
+from repro.kernels.fused_turn import ROW_TILE as _ROW_TILE
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +161,7 @@ def _ivf_family_step(index, sess, q, *, nprobe, k, alpha, list_scan):
     """
     h = sess.cache_ids.shape[0]
     # 1. centroid selection against the cached set C0  (cost: h)
-    csims = sess.cache_vecs @ q                      # (h,)
+    csims = jnp.matmul(sess.cache_vecs, q, precision=_ivf.F32)   # (h,)
     _, sel_local = jax.lax.top_k(csims, nprobe)
     sel_cached = sess.cache_ids[sel_local]           # (np,) global ids
 
@@ -227,7 +230,8 @@ def _ivf_family_step_batch(index, sess, q, *, nprobe, k, alpha, is_first,
     follow-up flushes stay O(B·h) instead of O(B·p).
     """
     b, h = sess.cache_ids.shape
-    csims = jnp.einsum("bhd,bd->bh", sess.cache_vecs, q)
+    csims = jnp.einsum("bhd,bd->bh", sess.cache_vecs, q,
+                       precision=_ivf.F32)
     _, sel_local = jax.lax.top_k(csims, nprobe)
     sel_cached = jnp.take_along_axis(sess.cache_ids, sel_local, axis=1)
 
@@ -341,6 +345,12 @@ class RetrievalBackend:
     def corpus_vectors(self, index) -> Optional[jax.Array]:
         return None
 
+    def place(self, index):
+        """``index`` laid out for this backend's scan.  The engines call
+        it once, when they place an index; identity unless a Pallas
+        kernel scans the index (``IVFBackend.place``)."""
+        return index
+
     def query_dim(self, index) -> int:
         """Embedding dimensionality queries against ``index`` must have."""
         raise NotImplementedError
@@ -439,6 +449,29 @@ class IVFBackend(RetrievalBackend):
         # the float scan's candidate pool: every slot of every probed list
         return self.nprobe * index.lmax
 
+    def _kernel_scans(self) -> bool:
+        """Does a Pallas list kernel (not its XLA reference) scan the
+        lists?  The sharded scan carries the same ``fused`` plugin."""
+        return (self.fused is not None and
+                (self.fused.mode or _kops.default_mode()) != "ref")
+
+    def _pad_lists(self, index, payload: str, fill):
+        lpad = tiling.list_len(index.lmax)
+        return index._replace(**{
+            payload: tiling.pad_rows_blocked(getattr(index, payload),
+                                             lpad, fill),
+            "list_ids": tiling.pad_rows_blocked(index.list_ids, lpad, -1)})
+
+    def place(self, index):
+        """Pad the list axis once to the length the Pallas list kernels
+        stream (``tiling.list_len``) when one of them scans it, so the
+        kernel wrappers scan in place instead of copying every list on
+        each call.  Pad slots carry id -1.  The XLA scan reads the
+        stored length and gets the index as built."""
+        if not self._kernel_scans():
+            return index
+        return self._pad_lists(index, "list_vecs", 0.0)
+
 
 @register
 @dataclasses.dataclass(frozen=True)
@@ -487,6 +520,26 @@ class IVFPQBackend(IVFBackend):
 
     def corpus_vectors(self, index):
         return index.doc_vecs
+
+    def _kernel_scans(self) -> bool:
+        # unfused and unsharded, lists go through the Pallas ADC kernel
+        if self.fused is None and self.scan is None:
+            return _kops.default_mode() != "ref"
+        return super()._kernel_scans()
+
+    def place(self, index):
+        """As ``IVFBackend.place`` for the code lists; the fused
+        single-device turn also DMAs its re-rank rows as whole
+        ``ROW_TILE``-row tiles, so the corpus rows are padded to a
+        multiple of that once here (pad rows are never a candidate)."""
+        if not self._kernel_scans():
+            return index
+        index = self._pad_lists(index, "list_codes", 0)
+        if self.fused is not None and self.scan is None:
+            n = index.doc_vecs.shape[0]
+            index = index._replace(doc_vecs=tiling.pad_axis(
+                index.doc_vecs, 0, -(-n // _ROW_TILE) * _ROW_TILE, 0.0))
+        return index
 
     def fetch_limit(self, index) -> int:
         # asking for k beyond this would widen the exact re-rank pool

@@ -21,11 +21,19 @@ from __future__ import annotations
 import functools
 from typing import NamedTuple, Optional, Tuple
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
 from repro.core import kmeans as _kmeans
 from repro.core.topk import masked_topk
+
+
+#: Precision of every float32 score the contracts compare: batched ==
+#: sequential, fused f32 == the XLA path, recall against exact search.
+#: At DEFAULT precision a TPU rounds f32 matmul operands to bf16 (the
+#: CPU computes in f32 either way), which moved ids on the chip.
+F32 = jax.lax.Precision.HIGHEST
 
 
 class IVFIndex(NamedTuple):
@@ -62,26 +70,43 @@ class SearchStats(NamedTuple):
 def build(vectors: jax.Array, p: int, *, iters: int = 10,
           key: Optional[jax.Array] = None,
           capacity_factor: float = 1.3) -> IVFIndex:
-    """Build the index: balanced k-means + bucketed posting-list layout."""
-    n, d = vectors.shape
+    """Build the index: balanced k-means + bucketed posting-list layout.
+
+    The list axis is the largest list's length; pad slots carry id -1,
+    which every scan masks.  An engine whose scan runs a Pallas list
+    kernel pads it to the kernel tile once, when it places the index
+    (``backend.RetrievalBackend.place``).
+    """
+    vectors = jnp.asarray(vectors)
     res = _kmeans.fit_balanced(vectors, p, iters=iters, key=key,
                                capacity_factor=capacity_factor)
-    lmax = int(jax.device_get(res.sizes.max()))
-    lmax = max(lmax, 1)
-    assign = jax.device_get(res.assignment)
-    # host-side bucketisation (index build is offline)
-    import numpy as np
+    list_ids = bucketize(jax.device_get(res.assignment), p)
+    return IVFIndex(res.centroids, gather_lists(vectors, list_ids),
+                    list_ids, res.sizes.astype(jnp.int32))
+
+
+def bucketize(assign, p: int) -> jax.Array:
+    """(p, max size) doc ids per list, ascending, -1 padded."""
+    assign = np.asarray(assign)
+    sizes = np.bincount(assign, minlength=p)
+    lmax = max(int(sizes.max()), 1)
+    docs = np.argsort(assign, kind="stable")
+    starts = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+    slot = np.arange(len(assign)) - starts[assign[docs]]
     ids = np.full((p, lmax), -1, np.int32)
-    fill = np.zeros(p, np.int64)
-    for doc, c in enumerate(assign):
-        ids[c, fill[c]] = doc
-        fill[c] += 1
-    list_ids = jnp.asarray(ids)
-    gather_idx = jnp.maximum(list_ids, 0)
-    list_vecs = jnp.where((list_ids >= 0)[..., None],
-                          vectors[gather_idx], 0.0)
-    return IVFIndex(res.centroids, list_vecs, list_ids,
-                    res.sizes.astype(jnp.int32))
+    ids[assign[docs], slot] = docs
+    return jnp.asarray(ids)
+
+
+@jax.jit
+def gather_lists(vectors: jax.Array, list_ids: jax.Array) -> jax.Array:
+    """(p, L, d) posting-list rows for ``list_ids``; zero rows at -1.
+
+    One gather into the output (ids -1 read out of range, filled with
+    0), so the build holds one list tensor, not a gather and a masked
+    copy of it."""
+    idx = jnp.where(list_ids >= 0, list_ids, vectors.shape[0])
+    return jnp.take(vectors, idx, axis=0, mode="fill", fill_value=0)
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +117,7 @@ def build(vectors: jax.Array, p: int, *, iters: int = 10,
 def exact_search(vectors: jax.Array, queries: jax.Array, k: int
                  ) -> Tuple[jax.Array, jax.Array]:
     """Brute-force top-k over the full collection (paper's 'Exact' row)."""
-    scores = queries @ vectors.T          # (B, n)
+    scores = jnp.matmul(queries, vectors.T, precision=F32)   # (B, n)
     return jax.lax.top_k(scores, k)
 
 
@@ -104,7 +129,7 @@ def _scan_lists(index: IVFIndex, queries: jax.Array, sel: jax.Array,
     """
     lv = index.list_vecs[sel]                       # (B, np, Lmax, d)
     li = index.list_ids[sel]                        # (B, np, Lmax)
-    scores = jnp.einsum("bd,bnld->bnl", queries, lv)
+    scores = jnp.einsum("bd,bnld->bnl", queries, lv, precision=F32)
     mask = li >= 0
     b = queries.shape[0]
     flat_scores = scores.reshape(b, -1)
@@ -127,7 +152,8 @@ def search(index: IVFIndex, queries: jax.Array, *, nprobe: int, k: int,
     Returns (scores (B,k), doc_ids (B,k), stats).
     """
     b = queries.shape[0]
-    cscores = queries @ index.centroids.T           # (B, p)
+    cscores = jnp.matmul(queries, index.centroids.T,
+                         precision=F32)             # (B, p)
     _, sel = jax.lax.top_k(cscores, nprobe)          # (B, np)
     top_v, top_i, real = (scan or _scan_lists)(index, queries, sel, k)
     stats = SearchStats(
@@ -153,7 +179,8 @@ def search_cached(index: IVFIndex, cache_ids: jax.Array, cache_vecs: jax.Array,
     """
     b = queries.shape[0]
     h = cache_ids.shape[0]
-    cscores = queries @ cache_vecs.T                # (B, h)
+    cscores = jnp.matmul(queries, cache_vecs.T,
+                         precision=F32)             # (B, h)
     _, sel_local = jax.lax.top_k(cscores, nprobe)   # (B, np) into cache
     sel_global = cache_ids[sel_local]               # (B, np) global ids
     top_v, top_i, real = _scan_lists(index, queries, sel_global, k)
@@ -173,6 +200,6 @@ def make_cache(index: IVFIndex, q0: jax.Array, *, h: int
 
     Returns (cache_ids (h,), cache_vecs (h,d)).
     """
-    cscores = index.centroids @ q0                  # (p,)
+    cscores = jnp.matmul(index.centroids, q0, precision=F32)   # (p,)
     _, ids = jax.lax.top_k(cscores, h)
     return ids.astype(jnp.int32), index.centroids[ids]

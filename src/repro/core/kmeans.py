@@ -112,9 +112,38 @@ def kmeans_fit(points: jax.Array, p: int, *, iters: int = 10,
     return centroids, assign
 
 
-@functools.partial(jax.jit, static_argnames=("p", "capacity", "n_choices"))
+#: points per block of the (points × centroids) similarity products in
+#: the balancing pass: a (block, p) f32 tile instead of the full (n, p)
+#: matrix, which at n = 2^20, p = 2^14 would be 64 GiB
+BLOCK = 4096
+
+
+def _blocked_top(points: jax.Array, centroids: jax.Array, r: int,
+                 block: int) -> Tuple[jax.Array, jax.Array]:
+    """Per point, the r most similar centroids: (sims (n, r), ids (n, r)).
+
+    Computed ``block`` points at a time so only a (block, p) similarity
+    tile is ever live."""
+    n, d = points.shape
+    block = min(block, n)
+    nb = -(-n // block)
+    padded = points
+    if nb * block != n:
+        padded = jnp.pad(points, ((0, nb * block - n), (0, 0)))
+
+    def one(x):
+        v, i = jax.lax.top_k(x @ centroids.T, r)
+        return v, i.astype(jnp.int32)
+
+    v, i = jax.lax.map(one, padded.reshape(nb, block, d))
+    return v.reshape(-1, r)[:n], i.reshape(-1, r)[:n]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("p", "capacity", "n_choices", "block"))
 def balance_assignment(points: jax.Array, centroids: jax.Array, p: int,
-                       capacity: int, n_choices: int = 4) -> Tuple[jax.Array, jax.Array]:
+                       capacity: int, n_choices: int = 4,
+                       block: int = BLOCK) -> Tuple[jax.Array, jax.Array]:
     """Capacity-constrained assignment: greedy spill to next-nearest centroid.
 
     Deterministic, vectorised: points are processed in similarity-priority
@@ -124,59 +153,72 @@ def balance_assignment(points: jax.Array, centroids: jax.Array, p: int,
     least-loaded cluster (no capacity bound; in practice this bucket is
     empty for capacity ≥ 1.25·n/p).
 
+    Memory is O(n·n_choices + block·p): the similarities are reduced to
+    each point's top ``n_choices`` centroids block by block, and a
+    point's rank within its candidate cluster comes from stable sorts
+    by (candidate, -similarity, point index).
+
     Returns (assignment (n,), sizes (p,)).
     """
     n = points.shape[0]
     n_choices = min(n_choices, p)
-    sims = points @ centroids.T                                  # (n, p)
-    choice_sims, choice_ids = jax.lax.top_k(sims, n_choices)     # (n, r)
-
-    assignment = jnp.full((n,), -1, jnp.int32)
-    sizes = jnp.zeros((p,), jnp.int32)
+    choice_sims, choice_ids = _blocked_top(points, centroids, n_choices,
+                                           block)
+    pos = jnp.arange(n, dtype=jnp.int32)
 
     def place_rank(carry, r):
         assignment, sizes = carry
-        cand = choice_ids[:, r]                                   # (n,)
         want = assignment < 0                                     # unplaced
-        # order unplaced points by similarity so the best-matching points
-        # win the remaining capacity of each cluster
+        # group the unplaced points by candidate cluster (placed ones go
+        # to the sentinel group p); within a group the best-matching
+        # points come first, ties by point index, so they win the
+        # cluster's remaining capacity
+        grp = jnp.where(want, choice_ids[:, r], p)
+        # two single-key sorts, not one three-key sort: the TPU compiler
+        # takes about half as long over them at n = 2^20
         order = jnp.argsort(jnp.where(want, -choice_sims[:, r], jnp.inf))
-        cand_o = cand[order]
-        want_o = want[order]
-        # rank of each point within its candidate cluster, among this batch
-        onehot_pos = jnp.cumsum(
-            jax.nn.one_hot(jnp.where(want_o, cand_o, p), p + 1, dtype=jnp.int32),
-            axis=0,
-        )
-        pos_in_cluster = jnp.take_along_axis(
-            onehot_pos, jnp.where(want_o, cand_o, p)[:, None], axis=1
-        )[:, 0] - 1                                               # 0-based
-        room = capacity - sizes[jnp.where(want_o, cand_o, 0)]
-        ok = want_o & (pos_in_cluster < room)
-        new_assign_o = jnp.where(ok, cand_o, -1)
-        # scatter back to original order
-        new_assign = jnp.zeros((n,), jnp.int32).at[order].set(new_assign_o)
-        placed_mask = jnp.zeros((n,), bool).at[order].set(ok)
-        assignment = jnp.where(placed_mask, new_assign, assignment)
+        g_s, i_s = jax.lax.sort((grp[order], order), num_keys=1,
+                                is_stable=True)
+        first = jnp.concatenate([jnp.ones((1,), bool), g_s[1:] != g_s[:-1]])
+        start = jax.lax.cummax(jnp.where(first, pos, 0))
+        pos_in_cluster = jnp.zeros((n,), jnp.int32).at[i_s].set(pos - start)
+        room = capacity - sizes[jnp.minimum(grp, p - 1)]
+        ok = want & (pos_in_cluster < room)
+        assignment = jnp.where(ok, grp, assignment)
         sizes = sizes + jax.ops.segment_sum(
-            placed_mask.astype(jnp.int32), jnp.where(placed_mask, assignment, p),
+            ok.astype(jnp.int32), jnp.where(ok, grp, p),
             num_segments=p + 1)[:p]
         return (assignment, sizes), None
 
     (assignment, sizes), _ = jax.lax.scan(
-        place_rank, (assignment, sizes), jnp.arange(n_choices))
+        place_rank, (jnp.full((n,), -1, jnp.int32),
+                     jnp.zeros((p,), jnp.int32)),
+        jnp.arange(n_choices))
 
-    # fallback: dump stragglers into the least-loaded cluster one by one
-    def fallback(carry, i):
-        assignment, sizes = carry
-        unplaced = assignment[i] < 0
+    # fallback: dump stragglers, in index order, into the least-loaded
+    # cluster one by one — a loop over the unplaced points only
+    n_left = jnp.sum(assignment < 0)
+    left = jnp.nonzero(assignment < 0, size=n, fill_value=0)[0]
+
+    def fallback(carry):
+        i, assignment, sizes = carry
         tgt = jnp.argmin(sizes).astype(jnp.int32)
-        assignment = assignment.at[i].set(jnp.where(unplaced, tgt, assignment[i]))
-        sizes = sizes.at[tgt].add(jnp.where(unplaced, 1, 0))
-        return (assignment, sizes), None
+        assignment = assignment.at[left[i]].set(tgt)
+        sizes = sizes.at[tgt].add(1)
+        return i + 1, assignment, sizes
 
-    (assignment, sizes), _ = jax.lax.scan(fallback, (assignment, sizes), jnp.arange(n))
+    _, assignment, sizes = jax.lax.while_loop(
+        lambda c: c[0] < n_left, fallback, (0, assignment, sizes))
     return assignment, sizes
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def mean_max_similarity(points: jax.Array, centroids: jax.Array, *,
+                        block: int = BLOCK) -> jax.Array:
+    """Mean over points of the best centroid similarity (the inertia),
+    blocked like ``balance_assignment``."""
+    v, _ = _blocked_top(points, centroids, 1, block)
+    return jnp.mean(v[:, 0])
 
 
 def fit_balanced(points: jax.Array, p: int, *, iters: int = 10,
@@ -187,6 +229,5 @@ def fit_balanced(points: jax.Array, p: int, *, iters: int = 10,
     centroids, _ = kmeans_fit(points, p, iters=iters, key=key)
     capacity = max(1, int(capacity_factor * n / p + 0.9999))
     assignment, sizes = balance_assignment(points, centroids, p, capacity)
-    sims = points @ centroids.T
-    inertia = jnp.mean(jnp.max(sims, axis=-1))
+    inertia = mean_max_similarity(points, centroids)
     return KMeansResult(centroids, assignment, sizes, inertia)

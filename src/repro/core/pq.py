@@ -28,6 +28,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import ivf as _ivf
 from repro.core import kmeans as _kmeans
 
 
@@ -67,8 +68,11 @@ def encode(book: PQCodebook, vectors: jax.Array) -> jax.Array:
     n, d = vectors.shape
     m, n_codes, d_sub = book.codewords.shape
     subs = vectors.reshape(n, m, d_sub)
-    # ||x - c||² = ||x||² - 2<x,c> + ||c||²; argmin over codewords
-    dots = jnp.einsum("nmd,mkd->nmk", subs, book.codewords)
+    # ||x - c||² = ||x||² - 2<x,c> + ||c||²; argmin over codewords.
+    # HIGHEST: bf16-rounded dots (a TPU's DEFAULT) pick a farther
+    # codeword whenever two are near, adding quantisation error
+    dots = jnp.einsum("nmd,mkd->nmk", subs, book.codewords,
+                      precision=jax.lax.Precision.HIGHEST)
     c_sq = jnp.sum(book.codewords ** 2, -1)                 # (m, k)
     return jnp.argmin(c_sq[None] - 2 * dots, axis=-1).astype(jnp.uint8)
 
@@ -88,7 +92,8 @@ def adc_table(book: PQCodebook, query: jax.Array) -> jax.Array:
     """Query → (m, 256) inner-product lookup table (built once/query)."""
     m, n_codes, d_sub = book.codewords.shape
     q = query.reshape(m, d_sub)
-    return jnp.einsum("md,mkd->mk", q, book.codewords)      # (m, 256)
+    return jnp.einsum("md,mkd->mk", q, book.codewords,
+                      precision=jax.lax.Precision.HIGHEST)  # (m, 256)
 
 
 @jax.jit
@@ -167,9 +172,7 @@ def build_ivf_pq(index, vectors: jax.Array, m: int, *, iters: int = 8,
     """
     book = train(vectors, m, iters=iters, key=key, n_codes=n_codes)
     codes = encode(book, vectors)                   # (n, m) uint8
-    gather = jnp.maximum(index.list_ids, 0)
-    list_codes = jnp.where((index.list_ids >= 0)[..., None],
-                           codes[gather], jnp.asarray(0, jnp.uint8))
+    list_codes = _ivf.gather_lists(codes, index.list_ids)
     return IVFPQIndex(index.centroids, book.codewords, list_codes,
                       index.list_ids, index.list_sizes.astype(jnp.int32),
                       jnp.asarray(vectors))

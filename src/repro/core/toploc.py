@@ -91,7 +91,8 @@ def _bcast_centroid_scores(centroids: jax.Array, q: jax.Array) -> jax.Array:
     """(B, p) centroid scores, bit-identical per row to ``centroids @ q``."""
     b = q.shape[0]
     return jnp.einsum("bpd,bd->bp",
-                      jnp.broadcast_to(centroids, (b,) + centroids.shape), q)
+                      jnp.broadcast_to(centroids, (b,) + centroids.shape), q,
+                      precision=_ivf.F32)
 
 
 @functools.partial(jax.jit, static_argnames=("h",))
@@ -115,7 +116,7 @@ def _adc_tables(index: _pq.IVFPQIndex, q: jax.Array) -> jax.Array:
     m, n_codes, d_sub = index.codewords.shape
     qs = q.reshape(b, m, d_sub)
     cw = jnp.broadcast_to(index.codewords, (b,) + index.codewords.shape)
-    return jnp.einsum("bmd,bmkd->bmk", qs, cw)
+    return jnp.einsum("bmd,bmkd->bmk", qs, cw, precision=_ivf.F32)
 
 
 def _scan_lists_pq(index: _pq.IVFPQIndex, q: jax.Array, sel: jax.Array,
@@ -170,9 +171,10 @@ class FusedTurn:
     the float path).
 
     Frozen + hashable so it rides on the backend dataclass as a
-    jit-static field.  ``mode=None`` follows ``kernels.ops`` dispatch
-    (interpret on CPU, compiled on TPU); ``mode="ref"`` forces the pure
-    XLA oracle in ``kernels.ref``.
+    jit-static field.  ``mode=None`` follows ``kernels.ops`` dispatch:
+    the pure-XLA oracle in ``kernels.ref`` off TPU, the compiled Pallas
+    kernel on TPU; ``mode="interpret"`` runs the kernel body in the
+    Pallas interpreter, ``mode="ref"`` forces the oracle.
     """
 
     precision: str = "f32"

@@ -266,7 +266,8 @@ class ShardedIVFScan:
             else:
                 lvs = lv[ss]                                  # (B,np,L,d)
                 lis = jnp.where(own[..., None], li[ss], -1)
-                scores = jnp.einsum("bd,bnld->bnl", q, lvs)
+                scores = jnp.einsum("bd,bnld->bnl", q, lvs,
+                                    precision=_ivf.F32)
                 flat_v = jnp.where(lis >= 0, scores,
                                    -jnp.inf).reshape(b, -1)
                 flat_i = lis.reshape(b, -1)

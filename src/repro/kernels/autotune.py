@@ -81,21 +81,40 @@ class DeviceModel:
     fig8 judge compares predictions made with the same model.
     """
     name: str
-    flops: float                 # peak f32 FLOP/s
+    flops: float                 # peak MXU FLOP/s
     hbm_bw: float                # HBM bytes/s
     dispatch_s: float            # per-kernel-launch host overhead
     step_s: float                # per-grid-step sequencing overhead
     sort_flop: float = 4.0       # compare-exchange cost multiplier
 
 
-TPU_MODEL = DeviceModel(name="tpu", flops=2.75e13, hbm_bw=1.2e12,
-                        dispatch_s=5e-6, step_s=1.5e-7)
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e: 197 TFLOP/s bf16 and 819 GB/s HBM (Google Cloud
+#: documentation, "TPU v5e").  The per-dispatch and per-grid-step
+#: overheads are model constants, not published figures.
+TPU_MODELS = {
+    "TPU v5 lite": DeviceModel(name="TPU v5 lite", flops=1.97e14,
+                               hbm_bw=8.19e11, dispatch_s=5e-6,
+                               step_s=1.5e-7),
+}
 CPU_MODEL = DeviceModel(name="cpu", flops=5e10, hbm_bw=2e10,
                         dispatch_s=3e-5, step_s=1e-6)
 
 
 def device_model() -> DeviceModel:
-    return TPU_MODEL if jax.default_backend() == "tpu" else CPU_MODEL
+    """Roofline terms of ``jax.devices()[0]``.  A TPU whose kind has no
+    entry in ``TPU_MODELS`` raises: a guessed peak would make every
+    prediction for it wrong without saying so."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return CPU_MODEL
+    try:
+        return TPU_MODELS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks recorded for TPU kind "
+            f"{dev.device_kind!r}; add them to autotune.TPU_MODELS"
+        ) from None
 
 
 # ---------------------------------------------------------------------------
@@ -139,7 +158,7 @@ def vmem_bytes(shape: TurnShape, cfg: TileConfig) -> int:
     s = shape
     t = resolve(shape, cfg)
     by = 0
-    by += s.b * s.d * 4                       # q block
+    by += 2 * s.b * s.d * 4                   # q blocks (B, d), (B, 1, d)
     by += t["blk"] * s.d * 4                  # centroid tile
     if s.family == "pq":
         by += s.b * s.m * 256 * 4             # ADC tables (≤256 codes)
@@ -148,7 +167,7 @@ def vmem_bytes(shape: TurnShape, cfg: TileConfig) -> int:
     by += 2 * t["blk_l"] * t["row_bytes"]     # lbuf, double-buffered
     by += 2 * t["blk_l"] * 4                  # ibuf
     if s.precision != "f32" or s.family == "pq":
-        by += t["r_pad"] * s.d * 4            # re-rank row gather
+        by += t["r_pad"] * 8 * s.d * 4        # re-rank 8-row tile gather
     by += 2 * s.b * t["kp"] * 4               # out_v/out_i blocks
     by += s.b * t["np_pad"] * 4               # out_sel block
     return by

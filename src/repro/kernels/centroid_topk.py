@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 from repro.kernels import sorting
 
@@ -98,7 +97,7 @@ def centroid_topk(queries: jax.Array, centroids: jax.Array, k: int, *,
             pltpu.VMEM((b, k), jnp.float32),
             pltpu.VMEM((b, k), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(queries, centroids)

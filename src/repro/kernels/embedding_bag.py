@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 
 def _kernel(ids_ref, w_ref, table_ref, o_ref, acc, *, bag: int):
@@ -76,7 +75,7 @@ def embedding_bag(table: jax.Array, ids: jax.Array,
             scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ids, weights.astype(jnp.float32), table)

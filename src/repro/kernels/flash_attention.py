@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 NEG_INF = -1e30
 
@@ -127,7 +126,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((blk_q, 1), jnp.float32),
             pltpu.VMEM((blk_q, dv), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, k.reshape(b * hkv, skv, d), v.reshape(b * hkv, skv, dv))
@@ -217,7 +216,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, group, dv), q.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(len_rows, qg, k.reshape(b * hkv, s, d), v.reshape(b * hkv, s, dv))

@@ -60,7 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 from repro.kernels import sorting
 
 PAD_POS = sorting.PAD_POS
@@ -95,8 +94,11 @@ def score_tile(q: jax.Array, tile: jax.Array, precision: str) -> jax.Array:
     divides.
     """
     if precision == "f32":
+        # HIGHEST, like the XLA path (``ivf.F32``): at DEFAULT the TPU
+        # would round the f32 operands to bf16
         return jax.lax.dot_general(
             q, tile, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
     if precision == "bf16":
         return jax.lax.dot_general(
@@ -106,9 +108,9 @@ def score_tile(q: jax.Array, tile: jax.Array, precision: str) -> jax.Array:
     if precision == "int8":
         qi, sq = quantize_sym(q, axes=(1,))               # (B, d), (B, 1)
         ti, st = quantize_sym(tile, axes=(0, 1))          # (T, d), (1, 1)
+        # int8 operands, int32 accumulation: the MXU's integer mode
         acc = jax.lax.dot_general(
-            qi.astype(jnp.int32), ti.astype(jnp.int32),
-            (((1,), (1,)), ((), ())),
+            qi, ti, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32)             # (B, T)
         return acc.astype(jnp.float32) / (sq * st[0])
     raise ValueError(f"unknown precision {precision!r}")
@@ -116,7 +118,7 @@ def score_tile(q: jax.Array, tile: jax.Array, precision: str) -> jax.Array:
 
 def adc_score_tile(table: jax.Array, codes: jax.Array, precision: str
                    ) -> jax.Array:
-    """ADC scores for one code tile: (m, C) table × (T, m) codes → (T,).
+    """ADC scores for one code tile: (m, C) table × (T, ≥m) codes → (T,).
 
     Realised as m one-hot MXU dots (cf. ``pq_adc``); bf16 casts the
     LUT, int8 quantises it with one scale per (m, C) table — tile
@@ -124,25 +126,26 @@ def adc_score_tile(table: jax.Array, codes: jax.Array, precision: str
     tiles, which keeps the ref emulation (a plain gather of the same
     integer LUT) exact.
     """
-    t, m = codes.shape
-    n_codes = table.shape[1]
+    t = codes.shape[0]
+    m, n_codes = table.shape          # codes may carry lane padding past m
     iota = jax.lax.broadcasted_iota(jnp.int32, (t, n_codes), 1)
     if precision == "int8":
         ti, st = quantize_sym(table, axes=(0, 1))         # (m, C) int8
         acc = jnp.zeros((t,), jnp.int32)
         for sq in range(m):
-            onehot = (iota == codes[:, sq:sq + 1]).astype(jnp.int32)
+            onehot = (iota == codes[:, sq:sq + 1]).astype(jnp.int8)
             acc = acc + jax.lax.dot_general(
-                onehot, ti[sq].astype(jnp.int32),
+                onehot, ti[sq],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
         return acc.astype(jnp.float32) / st[0, 0]
     tbl = table.astype(jnp.bfloat16) if precision == "bf16" else table
+    hi = jax.lax.Precision.HIGHEST if precision == "f32" else None
     scores = jnp.zeros((t,), jnp.float32)
     for sq in range(m):
         onehot = (iota == codes[:, sq:sq + 1]).astype(tbl.dtype)
         scores = scores + jax.lax.dot_general(
-            onehot, tbl[sq], (((1,), (0,)), ((), ())),
+            onehot, tbl[sq], (((1,), (0,)), ((), ())), precision=hi,
             preferred_element_type=jnp.float32)
     return scores
 
@@ -151,14 +154,43 @@ def _iota2(shape, dim):
     return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
 
 
-def rerank_exact(rows: jax.Array, qrow: jax.Array) -> jax.Array:
-    """Float32 exact re-rank: (R, d) rows × (1, d) query → (1, R).
+#: rows per re-rank DMA: HBM arrays are tiled (8, 128), so a copy may
+#: only start on, and span, whole 8-row sublane tiles
+ROW_TILE = 8
 
-    Explicit multiply-reduce (not a dot_general) mirroring
+
+def _gather_rerank(src_of, qrow, rblk, rsem, r_pad):
+    """Exact float32 scores of ``r_pad`` candidate rows, gathered from HBM.
+
+    ``src_of(i)`` → (hbm_ref, row) of candidate i; ``hbm_ref`` is a
+    2-D (rows, d) ref whose row count is a multiple of ``ROW_TILE``.
+    Each candidate's aligned ``ROW_TILE``-row tile is DMA'd into
+    ``rblk[i]``; its row is then picked with a one-hot max, which
+    returns the selected score exactly.  The score is an explicit
+    multiply-reduce (not a dot_general) mirroring
     ``toploc._scan_lists_pq``, so the fused and 3-dispatch paths lower
-    the same reduction and produce the same floats on CPU.
+    the same reduction.  Returns (1, r_pad).
     """
-    return jnp.sum(rows.astype(jnp.float32) * qrow, axis=-1)[None]
+    copies, offs = [], []
+    for i in range(r_pad):
+        ref, row = src_of(i)
+        start = pl.multiple_of((row // ROW_TILE) * ROW_TILE, ROW_TILE)
+        c = pltpu.make_async_copy(ref.at[pl.ds(start, ROW_TILE)],
+                                  rblk.at[i], rsem)
+        c.start()
+        copies.append(c)
+        offs.append(row - start)
+    for c in copies:
+        c.wait()
+    sub = _iota2((ROW_TILE, 1), 0)
+    lane = _iota2((1, r_pad), 1)
+    ex = jnp.full((1, r_pad), -jnp.inf, jnp.float32)
+    for i in range(r_pad):
+        s8 = jnp.sum(rblk[i].astype(jnp.float32) * qrow, axis=-1,
+                     keepdims=True)                        # (8, 1)
+        si = jnp.max(jnp.where(sub == offs[i], s8, -jnp.inf))
+        ex = jnp.where(lane == i, si, ex)
+    return ex
 
 
 # ---------------------------------------------------------------------------
@@ -170,18 +202,20 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
                  b: int, p: int, nc: int, blk_p: int, nprobe: int,
                  nsub: int, blk_l: int, lpad: int, np_pad: int,
                  r: int, r_pad: int, kp: int):
+    # per-query running state is (B, 1, n): Mosaic indexes the leading
+    # (untiled) dim dynamically, but not a sublane of a narrow tile
     if family == "pq":
-        (q_ref, cents_ref, tbl_ref, lists_hbm, li_hbm, corpus_hbm,
-         out_v, out_i, out_sel,
+        (q_ref, q3_ref, cents_ref, tbl_ref, lists_hbm, li_hbm,
+         corpus_hbm, out_v, out_i, out_sel,
          run_pv, run_pi, sel_smem, run_cv, run_ci, run_cp,
-         lbuf, ibuf, cand_smem, rrow, lsem, ssem, rsem) = refs
+         lbuf, ibuf, cand_smem, rblk, lsem, ssem, rsem) = refs
     elif do_rerank:
-        (q_ref, cents_ref, lists_hbm, li_hbm,
+        (q_ref, q3_ref, cents_ref, lists_hbm, li_hbm,
          out_v, out_i, out_sel,
          run_pv, run_pi, sel_smem, run_cv, run_ci, run_cp,
-         lbuf, ibuf, cand_smem, rrow, lsem, ssem, rsem) = refs
+         lbuf, ibuf, cand_smem, rblk, lsem, ssem, rsem) = refs
     else:
-        (q_ref, cents_ref, lists_hbm, li_hbm,
+        (q_ref, q3_ref, cents_ref, lists_hbm, li_hbm,
          out_v, out_i, out_sel,
          run_pv, run_pi, sel_smem, run_cv, run_ci, run_cp,
          lbuf, ibuf, lsem, ssem) = refs
@@ -198,8 +232,8 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
             lists_hbm.at[pid, pl.ds(sub * blk_l, blk_l)],
             lbuf.at[slot], lsem.at[slot, 0])
         ids = pltpu.make_async_copy(
-            li_hbm.at[pid, pl.ds(sub * blk_l, blk_l)],
-            ibuf.at[slot, 0], lsem.at[slot, 1])
+            li_hbm.at[pid, :, pl.ds(sub * blk_l, blk_l)],
+            ibuf.at[slot], lsem.at[slot, 1])
         return vec, ids
 
     # ---- stage 1: centroid tiles → running (B, np_pad) probe set -----
@@ -214,7 +248,11 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
         pos = j * blk_p + _iota2((b, blk_p), 1)
         valid = pos < p
         vals = jnp.where(valid, scores, -jnp.inf)
-        posm = jnp.where(valid, pos, PAD_POS)
+        # ``scores > inf`` never holds; selecting on it gives posm the
+        # scores' (B, blk_p) vector layout.  A bare iota is laid out
+        # sublane-replicated, and Mosaic aborts when the sorting
+        # network's jnp.roll slices such a value with B > 8 rows.
+        posm = jnp.where(valid & ~(scores > jnp.inf), pos, PAD_POS)
         # the global centroid index doubles as id and tie-break pos —
         # exactly lax.top_k's order over the flat centroid-score row
         bv, bi_, bp_ = sorting.block_topk_desc_tie(vals, posm, posm,
@@ -263,8 +301,7 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
             s = adc_score_tile(tbl, tile.astype(jnp.int32),
                                precision)[None]
         else:
-            qrow = q_ref[pl.ds(bq, 1), :]                 # (1, d)
-            s = score_tile(qrow, tile, precision)         # (1, blk_l)
+            s = score_tile(q3_ref[bq], tile, precision)   # (1, blk_l)
 
         pos = jp * blk_l + _iota2((1, blk_l), 1)
         valid = lid >= 0
@@ -273,11 +310,10 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
         bv, bi_, bp_ = sorting.block_topk_desc_tie(vals, lid, posm,
                                                    r_pad)
         mv, mi, mp = sorting.merge_topk_desc_tie(
-            run_cv[pl.ds(bq, 1), :], run_ci[pl.ds(bq, 1), :],
-            run_cp[pl.ds(bq, 1), :], bv, bi_, bp_)
-        run_cv[pl.ds(bq, 1), :] = mv
-        run_ci[pl.ds(bq, 1), :] = mi
-        run_cp[pl.ds(bq, 1), :] = mp
+            run_cv[bq], run_ci[bq], run_cp[bq], bv, bi_, bp_)
+        run_cv[bq] = mv
+        run_ci[bq] = mi
+        run_cp[bq] = mp
 
     # ---- stage 3: float32 in-kernel re-rank + write-out --------------
     @pl.when(j >= nc + b * npr)
@@ -287,37 +323,31 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
         @pl.when(j == nc + b * npr)
         def _sel_out():
             out_sel[...] = run_pi[...]
+            if do_rerank:
+                # every row's candidate keys move VMEM→SMEM in one copy:
+                # a DMA may not cut a 128-lane tile, which one (1, r_pad)
+                # row of the (B, 1, r_pad) state would
+                key_src = run_ci if family == "pq" else run_cp
+                cp = pltpu.make_async_copy(key_src, cand_smem, ssem)
+                cp.start()
+                cp.wait()
 
         if not do_rerank:
-            out_v[pl.ds(bq, 1), :] = run_cv[pl.ds(bq, 1), pl.ds(0, kp)]
-            out_i[pl.ds(bq, 1), :] = run_ci[pl.ds(bq, 1), pl.ds(0, kp)]
+            out_v[bq] = run_cv[bq][:, :kp]
+            out_i[bq] = run_ci[bq][:, :kp]
         else:
-            key_src = run_ci if family == "pq" else run_cp
-            cp = pltpu.make_async_copy(key_src.at[pl.ds(bq, 1)],
-                                       cand_smem, ssem)
-            cp.start()
-            cp.wait()
-            copies = []
-            for i in range(r_pad):
+            def src_of(i):
                 if family == "pq":
-                    row = jnp.maximum(cand_smem[0, i], 0)
-                    c = pltpu.make_async_copy(corpus_hbm.at[row],
-                                              rrow.at[i], rsem)
-                else:
-                    # flat pos → (probe, offset) → uncompressed list row
-                    cpos = cand_smem[0, i]
-                    probe_i = jnp.minimum(cpos // lpad, nprobe - 1)
-                    off = jax.lax.rem(cpos, lpad)
-                    pid2 = sel_smem[bq, probe_i]
-                    c = pltpu.make_async_copy(lists_hbm.at[pid2, off],
-                                              rrow.at[i], rsem)
-                c.start()
-                copies.append(c)
-            for c in copies:
-                c.wait()
-            qrow = q_ref[pl.ds(bq, 1), :]
-            ex = rerank_exact(rrow[...], qrow)            # (1, r_pad)
-            ids_row = run_ci[pl.ds(bq, 1), :]
+                    return corpus_hbm, jnp.maximum(cand_smem[bq, 0, i], 0)
+                # flat pos → (probe, offset) → uncompressed list row
+                cpos = cand_smem[bq, 0, i]
+                probe_i = jnp.minimum(cpos // lpad, nprobe - 1)
+                off = jax.lax.rem(cpos, lpad)
+                return lists_hbm.at[sel_smem[bq, probe_i]], off
+
+            ex = _gather_rerank(src_of, q3_ref[bq], rblk, rsem,
+                                r_pad)                    # (1, r_pad)
+            ids_row = run_ci[bq]
             rank = _iota2((1, r_pad), 1)
             # candidates past the exact depth r (pow2 padding) must not
             # re-enter: the 3-dispatch path never re-ranks them
@@ -325,41 +355,43 @@ def _turn_kernel(*refs, family: str, do_rerank: bool, precision: str,
             vals = jnp.where(valid, ex, -jnp.inf)
             bv, bi_, _ = sorting.block_topk_desc_tie(vals, ids_row,
                                                      rank, kp)
-            out_v[pl.ds(bq, 1), :] = bv
-            out_i[pl.ds(bq, 1), :] = bi_
+            out_v[bq] = bv
+            out_i[bq] = bi_
 
 
 def _turn_call(kern, *, family, do_rerank, b, d, m, n_codes, blk_p, nc,
-               blk_l, np_pad, r_pad, kp, grid, list_dtype, interpret):
+               blk_l, np_pad, r_pad, kp, grid, list_dtype, row_w,
+               interpret):
     def cents_map(j):
         return (jnp.minimum(j, nc - 1), 0)
 
     in_specs = [
         pl.BlockSpec((b, d), lambda j: (0, 0)),
+        pl.BlockSpec((b, 1, d), lambda j: (0, 0, 0)),
         pl.BlockSpec((blk_p, d), cents_map),
     ]
     if family == "pq":
         in_specs.append(pl.BlockSpec((b, m, n_codes),
                                      lambda j: (0, 0, 0)))
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))      # lists
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))      # list ids
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))      # lists
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))      # list ids
     if family == "pq":
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # corpus
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # corpus
 
     scratch = [
         pltpu.VMEM((b, np_pad), jnp.float32),        # run_pv
         pltpu.VMEM((b, np_pad), jnp.int32),          # run_pi
         pltpu.SMEM((b, np_pad), jnp.int32),          # sel_smem
-        pltpu.VMEM((b, r_pad), jnp.float32),         # run_cv
-        pltpu.VMEM((b, r_pad), jnp.int32),           # run_ci
-        pltpu.VMEM((b, r_pad), jnp.int32),           # run_cp
-        pltpu.VMEM((2, blk_l, m if family == "pq" else d),
-                   list_dtype),                      # lbuf
+        pltpu.VMEM((b, 1, r_pad), jnp.float32),      # run_cv
+        pltpu.VMEM((b, 1, r_pad), jnp.int32),        # run_ci
+        pltpu.VMEM((b, 1, r_pad), jnp.int32),        # run_cp
+        pltpu.VMEM((2, blk_l, row_w), list_dtype),   # lbuf
         pltpu.VMEM((2, 1, blk_l), jnp.int32),        # ibuf
     ]
     if do_rerank:
-        scratch.append(pltpu.SMEM((1, r_pad), jnp.int32))      # cand_smem
-        scratch.append(pltpu.VMEM((r_pad, d), jnp.float32))    # rrow
+        scratch.append(pltpu.SMEM((b, 1, r_pad), jnp.int32))   # cand_smem
+        scratch.append(pltpu.VMEM((r_pad, ROW_TILE, d),
+                                  jnp.float32))                # rblk
     scratch.append(pltpu.SemaphoreType.DMA((2, 2)))            # lsem
     scratch.append(pltpu.SemaphoreType.DMA)                    # ssem
     if do_rerank:
@@ -370,17 +402,17 @@ def _turn_call(kern, *, family, do_rerank, b, d, m, n_codes, blk_p, nc,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((b, kp), lambda j: (0, 0)),
-            pl.BlockSpec((b, kp), lambda j: (0, 0)),
+            pl.BlockSpec((b, 1, kp), lambda j: (0, 0, 0)),
+            pl.BlockSpec((b, 1, kp), lambda j: (0, 0, 0)),
             pl.BlockSpec((b, np_pad), lambda j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, kp), jnp.float32),
-            jax.ShapeDtypeStruct((b, kp), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, kp), jnp.int32),
             jax.ShapeDtypeStruct((b, np_pad), jnp.int32),
         ],
         scratch_shapes=scratch,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )
@@ -391,6 +423,7 @@ def _turn_dims(centroids, lists, nprobe, k, r, blk_p, blk_l):
     p, lpad = lists.shape[0], lists.shape[1]
     assert p_pad % blk_p == 0 and lpad % blk_l == 0, \
         (p_pad, blk_p, lpad, blk_l)
+    assert lpad % ROW_TILE == 0, lpad
     nc = p_pad // blk_p
     nsub = lpad // blk_l
     kp = _next_pow2(k)
@@ -436,8 +469,10 @@ def fused_turn(queries: jax.Array, centroids: jax.Array,
         kern, family="ivf", do_rerank=do_rerank, b=b, d=d, m=0,
         n_codes=0, blk_p=blk_p, nc=nc, blk_l=blk_l, np_pad=np_pad,
         r_pad=r_pad, kp=kp, grid=(nc + b * nprobe * nsub + b,),
-        list_dtype=jnp.float32, interpret=interpret)
-    return call(queries, centroids, list_vecs, list_ids)
+        list_dtype=jnp.float32, row_w=d, interpret=interpret)
+    v, i, sel = call(queries, queries[:, None], centroids, list_vecs,
+                     list_ids[:, None])
+    return v[:, 0], i[:, 0], sel
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -452,14 +487,17 @@ def fused_turn_pq(queries: jax.Array, centroids: jax.Array,
     """Single-dispatch IVF-PQ turn: centroid top-k + ADC + exact re-rank.
 
     tables (B, m, n_codes) f32 per-query ADC LUTs; list_codes
-    (p, lpad, m) uint8; corpus (N, d) f32 re-rank rows gathered by
-    candidate doc id.  ``r`` = ADC re-rank depth
+    (p, lpad, mw) uint8 with mw ≥ m (Mosaic DMAs whole 128-lane rows,
+    so the ops wrapper pads the code width to a lane multiple); corpus
+    (N, d) f32 re-rank rows gathered by candidate doc id, N a multiple
+    of ``ROW_TILE``.  ``r`` = ADC re-rank depth
     (max(k, min(rerank, nprobe·lmax)) upstream).  Stage 3 always runs.
     """
     b, d = queries.shape
     _, m, n_codes = tables.shape
     p, nc, nsub, lpad, kp, np_pad, r_pad = _turn_dims(
         centroids, list_codes, nprobe, k, r, blk_p, blk_l)
+    assert corpus.shape[0] % ROW_TILE == 0, corpus.shape
 
     kern = functools.partial(
         _turn_kernel, family="pq", do_rerank=True, precision=precision,
@@ -470,8 +508,11 @@ def fused_turn_pq(queries: jax.Array, centroids: jax.Array,
         n_codes=n_codes, blk_p=blk_p, nc=nc, blk_l=blk_l,
         np_pad=np_pad, r_pad=r_pad, kp=kp,
         grid=(nc + b * nprobe * nsub + b,),
-        list_dtype=jnp.uint8, interpret=interpret)
-    return call(queries, centroids, tables, list_codes, list_ids, corpus)
+        list_dtype=jnp.uint8, row_w=list_codes.shape[2],
+        interpret=interpret)
+    v, i, sel = call(queries, queries[:, None], centroids, tables,
+                     list_codes, list_ids[:, None], corpus)
+    return v[:, 0], i[:, 0], sel
 
 
 # ---------------------------------------------------------------------------
@@ -486,7 +527,7 @@ def _scan_kernel(sel_ref, own_ref, *refs, family: str, do_rerank: bool,
         if do_rerank:
             (tbl_ref, q_ref, tiles_ref, li_ref, corpus_hbm,
              out_v, out_i, out_p, run_v, run_i, run_p,
-             cand_smem, rrow, rsem, ssem) = refs
+             cand_smem, rblk, rsem, ssem) = refs
         else:
             (tbl_ref, tiles_ref, li_ref, out_v, out_i, out_p,
              run_v, run_i, run_p) = refs
@@ -494,7 +535,7 @@ def _scan_kernel(sel_ref, own_ref, *refs, family: str, do_rerank: bool,
         if do_rerank:
             (q_ref, tiles_ref, li_ref, lists_hbm,
              out_v, out_i, out_p, run_v, run_i, run_p,
-             cand_smem, rrow, rsem, ssem) = refs
+             cand_smem, rblk, rsem, ssem) = refs
         else:
             (q_ref, tiles_ref, li_ref, out_v, out_i, out_p,
              run_v, run_i, run_p) = refs
@@ -512,7 +553,7 @@ def _scan_kernel(sel_ref, own_ref, *refs, family: str, do_rerank: bool,
     @pl.when(j < npr)
     def _scan():
         tile = tiles_ref[0]                               # (blk_l, d|m)
-        lid = li_ref[...]                                 # (1, blk_l)
+        lid = li_ref[0]                                   # (1, blk_l)
         # shard ownership mask (sharded locals): foreign lists → -1,
         # matching ShardedIVFScan's where(own, li, -1)
         lid_m = jnp.where(own_ref[bi, j // nsub] > 0, lid, -1)
@@ -520,7 +561,7 @@ def _scan_kernel(sel_ref, own_ref, *refs, family: str, do_rerank: bool,
             s = adc_score_tile(tbl_ref[0], tile.astype(jnp.int32),
                                precision)[None]
         else:
-            s = score_tile(q_ref[...], tile, precision)   # (1, blk_l)
+            s = score_tile(q_ref[0], tile, precision)     # (1, blk_l)
         pos = j * blk_l + _iota2((1, blk_l), 1)
         valid = lid_m >= 0
         vals = jnp.where(valid, s, -jnp.inf)
@@ -536,42 +577,35 @@ def _scan_kernel(sel_ref, own_ref, *refs, family: str, do_rerank: bool,
     @pl.when(j == npr)
     def _finalize():
         if not do_rerank:
-            out_v[...] = run_v[...]
-            out_i[...] = run_i[...]
-            out_p[...] = run_p[...]
+            out_v[0] = run_v[...]
+            out_i[0] = run_i[...]
+            out_p[0] = run_p[...]
         else:
             key_src = run_i if family == "pq" else run_p
             cp = pltpu.make_async_copy(key_src, cand_smem, ssem)
             cp.start()
             cp.wait()
-            copies = []
-            for i in range(r_pad):
+
+            def src_of(i):
                 if family == "pq":
-                    row = jnp.maximum(cand_smem[0, i], 0)
-                    c = pltpu.make_async_copy(corpus_hbm.at[row],
-                                              rrow.at[i], rsem)
-                else:
-                    cpos = cand_smem[0, i]
-                    probe_i = jnp.minimum(cpos // lpad, nprobe - 1)
-                    off = jax.lax.rem(cpos, lpad)
-                    pid2 = sel_ref[bi, probe_i]
-                    c = pltpu.make_async_copy(lists_hbm.at[pid2, off],
-                                              rrow.at[i], rsem)
-                c.start()
-                copies.append(c)
-            for c in copies:
-                c.wait()
-            ex = rerank_exact(rrow[...], q_ref[...])      # (1, r_pad)
+                    return corpus_hbm, jnp.maximum(cand_smem[0, i], 0)
+                cpos = cand_smem[0, i]
+                probe_i = jnp.minimum(cpos // lpad, nprobe - 1)
+                off = jax.lax.rem(cpos, lpad)
+                return lists_hbm.at[sel_ref[bi, probe_i]], off
+
+            ex = _gather_rerank(src_of, q_ref[0], rblk, rsem,
+                                r_pad)                    # (1, r_pad)
             rank = _iota2((1, r_pad), 1)
             valid = (run_i[...] >= 0) & (rank < r)
             vals = jnp.where(valid, ex, -jnp.inf)
             bv, bi_, bp_ = sorting.block_topk_desc_tie(
                 vals, run_i[...], rank, kp)
-            out_v[...] = bv
-            out_i[...] = bi_
+            out_v[0] = bv
+            out_i[0] = bi_
             # after re-rank the tie-break key is the candidate's ADC
             # rank, not a flat scan position (single-device use only)
-            out_p[...] = bp_
+            out_p[0] = bp_
 
 
 def _scan_call(kern, *, family, do_rerank, b, d, m, n_codes, blk_l,
@@ -582,27 +616,31 @@ def _scan_call(kern, *, family, do_rerank, b, d, m, n_codes, blk_l,
 
     def li_map(bi, j, sel_ref, own_ref):
         jj = jnp.minimum(j, npr - 1)
-        return (sel_ref[bi, jj // nsub], jj % nsub)
+        return (sel_ref[bi, jj // nsub], 0, jj % nsub)
 
+    # per-row operands travel as (rows, 1, n) with (1, 1, n) blocks:
+    # Mosaic tiles the last two block dims by (8, 128) unless a block
+    # spans the whole array dimension, which a (1, n) row block of a
+    # (rows, n) array does not
     def row_map(bi, j, sel_ref, own_ref):
-        return (bi, 0)
+        return (bi, 0, 0)
 
     in_specs = []
     if family == "pq":
         in_specs.append(pl.BlockSpec(
             (1, m, n_codes), lambda bi, j, s, o: (bi, 0, 0)))
         if do_rerank:
-            in_specs.append(pl.BlockSpec((1, d), row_map))
+            in_specs.append(pl.BlockSpec((1, 1, d), row_map))
         in_specs.append(pl.BlockSpec((1, blk_l, m), lv_map))
-        in_specs.append(pl.BlockSpec((1, blk_l), li_map))
+        in_specs.append(pl.BlockSpec((1, 1, blk_l), li_map))
         if do_rerank:
-            in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     else:
-        in_specs.append(pl.BlockSpec((1, d), row_map))
+        in_specs.append(pl.BlockSpec((1, 1, d), row_map))
         in_specs.append(pl.BlockSpec((1, blk_l, d), lv_map))
-        in_specs.append(pl.BlockSpec((1, blk_l), li_map))
+        in_specs.append(pl.BlockSpec((1, 1, blk_l), li_map))
         if do_rerank:
-            in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     scratch = [
         pltpu.VMEM((1, r_pad), jnp.float32),
@@ -611,7 +649,7 @@ def _scan_call(kern, *, family, do_rerank, b, d, m, n_codes, blk_l,
     ]
     if do_rerank:
         scratch.append(pltpu.SMEM((1, r_pad), jnp.int32))
-        scratch.append(pltpu.VMEM((r_pad, d), jnp.float32))
+        scratch.append(pltpu.VMEM((r_pad, ROW_TILE, d), jnp.float32))
         scratch.append(pltpu.SemaphoreType.DMA)
         scratch.append(pltpu.SemaphoreType.DMA)
 
@@ -622,18 +660,18 @@ def _scan_call(kern, *, family, do_rerank, b, d, m, n_codes, blk_l,
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, w), row_map),
-                pl.BlockSpec((1, w), row_map),
-                pl.BlockSpec((1, w), row_map),
+                pl.BlockSpec((1, 1, w), row_map),
+                pl.BlockSpec((1, 1, w), row_map),
+                pl.BlockSpec((1, 1, w), row_map),
             ],
             scratch_shapes=scratch,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, w), jnp.float32),
-            jax.ShapeDtypeStruct((b, w), jnp.int32),
-            jax.ShapeDtypeStruct((b, w), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, w), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, w), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, w), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )
@@ -675,10 +713,10 @@ def fused_scan(queries: jax.Array, list_vecs: jax.Array,
         kern, family="ivf", do_rerank=rerank, b=b, d=d, m=0, n_codes=0,
         blk_l=blk_l, nsub=nsub, npr=npr, r_pad=r_pad, w=w,
         grid=(b, npr + 1), interpret=interpret)
-    args = (sel, own, queries, list_vecs, list_ids)
+    args = (sel, own, queries[:, None], list_vecs, list_ids[:, None])
     if rerank:
         args = args + (list_vecs,)
-    return call(*args)
+    return tuple(o[:, 0] for o in call(*args))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -699,6 +737,7 @@ def fused_scan_pq(tables: jax.Array, queries: jax.Array,
     b, m, n_codes = tables.shape
     p, lpad, _ = list_codes.shape
     d = queries.shape[1]
+    assert not rerank or corpus.shape[0] % ROW_TILE == 0, corpus.shape
     nprobe = sel.shape[1]
     assert lpad % blk_l == 0, (lpad, blk_l)
     nsub = lpad // blk_l
@@ -716,7 +755,10 @@ def fused_scan_pq(tables: jax.Array, queries: jax.Array,
         kern, family="pq", do_rerank=rerank, b=b, d=d, m=m,
         n_codes=n_codes, blk_l=blk_l, nsub=nsub, npr=npr, r_pad=r_pad,
         w=w, grid=(b, npr + 1), interpret=interpret)
+    li = list_ids[:, None]
     if rerank:
-        return call(sel, own, tables, queries, list_codes, list_ids,
+        outs = call(sel, own, tables, queries[:, None], list_codes, li,
                     corpus)
-    return call(sel, own, tables, list_codes, list_ids)
+    else:
+        outs = call(sel, own, tables, list_codes, li)
+    return tuple(o[:, 0] for o in outs)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 from repro.kernels import sorting
 
@@ -114,7 +113,7 @@ def ivf_scan(queries: jax.Array, list_vecs: jax.Array, list_ids: jax.Array,
             jax.ShapeDtypeStruct((b, k), jnp.float32),
             jax.ShapeDtypeStruct((b, k), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(sel, queries, list_vecs, list_ids)

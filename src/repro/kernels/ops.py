@@ -188,12 +188,25 @@ def fused_turn_pq(queries: jax.Array, centroids: jax.Array,
         return ref.fused_turn_pq(queries, c, tables, codes, li, corpus,
                                  p=p, lmax=lmax, nprobe=nprobe, k=k,
                                  r=r, precision=precision, blk_p=blk)
+    # the kernel DMAs code rows itself, and Mosaic only DMAs whole
+    # 128-lane rows: a per-call copy of the codes (their HBM tiles
+    # already pad m to 128 lanes, so the copy is no larger than them)
+    codes = _pad_axis(codes, 2, -(-m // tiling.LANE) * tiling.LANE, 0)
+    corpus = _row_tiles(corpus)
     v, i, s = _ft.fused_turn_pq(queries, c, tables.astype(jnp.float32),
                                 codes, li, corpus, nprobe=nprobe, k=k,
                                 r=r, precision=precision, blk_p=blk,
                                 blk_l=blk_l,
                                 interpret=(mode == "interpret"))
     return v[:, :k], i[:, :k], s[:, :nprobe]
+
+
+def _row_tiles(corpus: jax.Array) -> jax.Array:
+    """Corpus rows padded to whole ``ROW_TILE`` tiles, the unit the
+    in-kernel re-rank DMAs (an engine-placed index already is:
+    ``backend.IVFPQBackend.place``; pad rows are never a candidate)."""
+    n = corpus.shape[0]
+    return _pad_axis(corpus, 0, -(-n // _ft.ROW_TILE) * _ft.ROW_TILE, 0.0)
 
 
 def _convert_pos(pp: jax.Array, lpad: int, lmax: int) -> jax.Array:
@@ -278,6 +291,8 @@ def fused_scan_pq(tables: jax.Array, queries: jax.Array,
                                  corpus, lmax=lmax, k=k, r=r,
                                  precision=precision,
                                  rerank=fuse_rerank)
+    if fuse_rerank:
+        corpus = _row_tiles(corpus)
     v, i, pp = _ft.fused_scan_pq(tables.astype(jnp.float32), queries,
                                  codes, li, sel, own.astype(jnp.int32),
                                  corpus, k=k, r=r, precision=precision,

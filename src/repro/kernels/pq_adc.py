@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 from repro.kernels import sorting
 
 
@@ -49,18 +48,21 @@ def _kernel(sel_ref, tbl_ref, codes_ref, ids_ref, out_v_ref, out_i_ref,
 
     table = tbl_ref[...][0].astype(jnp.float32)           # (m, n_codes)
     codes = codes_ref[...][0].astype(jnp.int32)           # (blk_l, m)
-    li = ids_ref[...]                                     # (1, blk_l)
+    li = ids_ref[0]                                       # (1, blk_l)
     blk_l, m = codes.shape
     n_codes = table.shape[1]
 
     # ADC: scores[l] = sum_j table[j, codes[l, j]], realised as m
-    # one-hot MXU dots (compare-with-iota selects the LUT entry)
+    # one-hot MXU dots (compare-with-iota selects the LUT entry); at
+    # HIGHEST the selected f32 entry is exact, at DEFAULT the TPU would
+    # round the LUT to bf16
     iota = jax.lax.broadcasted_iota(jnp.int32, (blk_l, n_codes), 1)
     scores = jnp.zeros((blk_l,), jnp.float32)
     for sq in range(m):
         onehot = (iota == codes[:, sq:sq + 1]).astype(jnp.float32)
         scores = scores + jax.lax.dot_general(
             onehot, table[sq], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
     scores = jnp.where(li[0] >= 0, scores, -jnp.inf)[None]   # (1, blk_l)
 
@@ -71,8 +73,8 @@ def _kernel(sel_ref, tbl_ref, codes_ref, ids_ref, out_v_ref, out_i_ref,
 
     @pl.when(j == nprobe * nsub - 1)
     def _finalize():
-        out_v_ref[...] = run_v[...]
-        out_i_ref[...] = run_i[...]
+        out_v_ref[0] = run_v[...]
+        out_i_ref[0] = run_i[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "blk_l", "interpret"))
@@ -90,6 +92,12 @@ def pq_adc_scan(tables: jax.Array, list_codes: jax.Array,
     top-k candidates, to be exact-re-ranked by the caller.
     Padding contract (ops.py): Lmax multiple of blk_l, blk_l & k pow2,
     k ≤ blk_l.
+
+    Mosaic tiles the last two block dims by (8, 128) unless a block
+    spans the whole array dimension.  The per-row operands (one list's
+    ids, one query's outputs) therefore travel as ``(rows, 1, n)``
+    arrays with ``(1, 1, n)`` blocks rather than ``(1, n)`` blocks over
+    ``(rows, n)``.
     """
     b, m, n_codes = tables.shape
     p, lmax, _ = list_codes.shape
@@ -107,7 +115,10 @@ def pq_adc_scan(tables: jax.Array, list_codes: jax.Array,
         return (sel_ref[bi, j // nsub], j % nsub, 0)
 
     def ids_map(bi, j, sel_ref):
-        return (sel_ref[bi, j // nsub], j % nsub)
+        return (sel_ref[bi, j // nsub], 0, j % nsub)
+
+    def row_map(bi, j, sel_ref):
+        return (bi, 0, 0)
 
     out_v, out_i = pl.pallas_call(
         kern,
@@ -118,11 +129,11 @@ def pq_adc_scan(tables: jax.Array, list_codes: jax.Array,
                 pl.BlockSpec((1, m, n_codes),
                              lambda bi, j, sel_ref: (bi, 0, 0)),
                 pl.BlockSpec((1, blk_l, m), codes_map),
-                pl.BlockSpec((1, blk_l), ids_map),
+                pl.BlockSpec((1, 1, blk_l), ids_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, k), lambda bi, j, sel_ref: (bi, 0)),
-                pl.BlockSpec((1, k), lambda bi, j, sel_ref: (bi, 0)),
+                pl.BlockSpec((1, 1, k), row_map),
+                pl.BlockSpec((1, 1, k), row_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((1, k), jnp.float32),
@@ -130,11 +141,11 @@ def pq_adc_scan(tables: jax.Array, list_codes: jax.Array,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(sel, tables, list_codes, list_ids)
-    return out_v, out_i
+    )(sel, tables, list_codes, list_ids[:, None, :])
+    return out_v[:, 0], out_i[:, 0]

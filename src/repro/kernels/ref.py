@@ -120,7 +120,7 @@ def _fused_stage1_scores(queries: jax.Array, cents_pad: jax.Array,
         c = cents_pad[:p]
         return jnp.einsum("bpd,bd->bp",
                           jnp.broadcast_to(c, (queries.shape[0],) + c.shape),
-                          queries)
+                          queries, precision=jax.lax.Precision.HIGHEST)
     nc = cents_pad.shape[0] // blk_p
     tiles = cents_pad.reshape(nc, blk_p, -1)
     s = jnp.concatenate(
@@ -135,7 +135,8 @@ def _fused_stage2_scores(queries: jax.Array, lv_pad: jax.Array,
     """Probed-list scores (B, np, lmax) under the precision contract."""
     if precision == "f32":
         lv = lv_pad[:, :lmax][sel]
-        return jnp.einsum("bd,bnld->bnl", queries, lv)
+        return jnp.einsum("bd,bnld->bnl", queries, lv,
+                          precision=jax.lax.Precision.HIGHEST)
     b = queries.shape[0]
     npb = sel.shape[1]
     lpad, d = lv_pad.shape[1], lv_pad.shape[2]

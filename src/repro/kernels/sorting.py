@@ -36,10 +36,32 @@ def _compare_exchange(vals: jax.Array, ids: jax.Array, dist: int,
                    jnp.roll(vals, dist, axis=-1))
     pi = jnp.where(is_lo, jnp.roll(ids, -dist, axis=-1),
                    jnp.roll(ids, dist, axis=-1))
-    take_partner = jnp.where(keep_max, pv > vals, pv < vals)
+    # boolean selects are spelled as logic ops: Mosaic cannot lower a
+    # select (or a constant) whose values are i1
+    take_partner = (keep_max & (pv > vals)) | (~keep_max & (pv < vals))
     new_v = jnp.where(take_partner, pv, vals)
     new_i = jnp.where(take_partner, pi, ids)
     return new_v, new_i
+
+
+def reverse_lanes(x: jax.Array) -> jax.Array:
+    """``jnp.flip`` on the last (power-of-two) axis from roll + where.
+
+    Mosaic has no lowering for ``lax.rev``.  Swapping the two halves of
+    every 2·dist block, for dist = n/2 … 1, flips every bit of the lane
+    index, i.e. maps lane i to n-1-i.  Pure data movement, so the result
+    is bit-identical to ``jnp.flip``.
+    """
+    n = x.shape[-1]
+    assert _is_pow2(n), f"lane reversal needs power-of-two length, got {n}"
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    dist = n // 2
+    while dist >= 1:
+        is_lo = (iota % (2 * dist)) < dist
+        x = jnp.where(is_lo, jnp.roll(x, -dist, axis=-1),
+                      jnp.roll(x, dist, axis=-1))
+        dist //= 2
+    return x
 
 
 def bitonic_sort_desc(vals: jax.Array, ids: jax.Array
@@ -51,12 +73,11 @@ def bitonic_sort_desc(vals: jax.Array, ids: jax.Array
     stage = 2
     while stage <= n:
         desc = (iota & stage) == 0          # per-block direction
-        if stage == n:
-            desc = jnp.ones_like(desc)      # final merge: fully descending
         dist = stage // 2
         while dist >= 1:
             is_lo = (iota % (2 * dist)) < dist
-            keep_max = is_lo == desc
+            # final merge (stage == n) is fully descending
+            keep_max = is_lo if stage == n else ~(is_lo ^ desc)
             vals, ids = _compare_exchange(vals, ids, dist, keep_max)
             dist //= 2
         stage *= 2
@@ -68,7 +89,7 @@ def bitonic_merge_desc(vals: jax.Array, ids: jax.Array
     """Descending merge of a *bitonic* sequence along the last axis.
 
     Input convention: first half sorted descending, second half sorted
-    ascending (i.e. ``concat(run_desc, flip(block_desc))``).
+    ascending (i.e. ``concat(run_desc, reverse_lanes(block_desc))``).
     """
     n = vals.shape[-1]
     assert _is_pow2(n), f"bitonic merge needs power-of-two length, got {n}"
@@ -88,8 +109,8 @@ def merge_topk_desc(run_v: jax.Array, run_i: jax.Array,
 
     Shapes: (..., k) each; k power of two.
     """
-    v = jnp.concatenate([run_v, jnp.flip(blk_v, axis=-1)], axis=-1)
-    i = jnp.concatenate([run_i, jnp.flip(blk_i, axis=-1)], axis=-1)
+    v = jnp.concatenate([run_v, reverse_lanes(blk_v)], axis=-1)
+    i = jnp.concatenate([run_i, reverse_lanes(blk_i)], axis=-1)
     v, i = bitonic_merge_desc(v, i)
     k = run_v.shape[-1]
     return v[..., :k], i[..., :k]
@@ -134,7 +155,7 @@ def _compare_exchange_tie(vals: jax.Array, ids: jax.Array, pos: jax.Array,
     pp = jnp.where(is_lo, jnp.roll(pos, -dist, axis=-1),
                    jnp.roll(pos, dist, axis=-1))
     gt = (pv > vals) | ((pv == vals) & (pp < pos))   # partner ranks higher
-    take_partner = jnp.where(keep_max, gt, ~gt)
+    take_partner = ~(keep_max ^ gt)
     new_v = jnp.where(take_partner, pv, vals)
     new_i = jnp.where(take_partner, pi, ids)
     new_p = jnp.where(take_partner, pp, pos)
@@ -150,12 +171,10 @@ def bitonic_sort_desc_tie(vals: jax.Array, ids: jax.Array, pos: jax.Array
     stage = 2
     while stage <= n:
         desc = (iota & stage) == 0
-        if stage == n:
-            desc = jnp.ones_like(desc)
         dist = stage // 2
         while dist >= 1:
             is_lo = (iota % (2 * dist)) < dist
-            keep_max = is_lo == desc
+            keep_max = is_lo if stage == n else ~(is_lo ^ desc)
             vals, ids, pos = _compare_exchange_tie(vals, ids, pos, dist,
                                                    keep_max)
             dist //= 2
@@ -182,9 +201,9 @@ def merge_topk_desc_tie(run_v: jax.Array, run_i: jax.Array,
                         blk_i: jax.Array, blk_p: jax.Array,
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Merge two tiles sorted by (value desc, position asc); keep top-k."""
-    v = jnp.concatenate([run_v, jnp.flip(blk_v, axis=-1)], axis=-1)
-    i = jnp.concatenate([run_i, jnp.flip(blk_i, axis=-1)], axis=-1)
-    p = jnp.concatenate([run_p, jnp.flip(blk_p, axis=-1)], axis=-1)
+    v = jnp.concatenate([run_v, reverse_lanes(blk_v)], axis=-1)
+    i = jnp.concatenate([run_i, reverse_lanes(blk_i)], axis=-1)
+    p = jnp.concatenate([run_p, reverse_lanes(blk_p)], axis=-1)
     v, i, p = bitonic_merge_desc_tie(v, i, p)
     k = run_v.shape[-1]
     return v[..., :k], i[..., :k], p[..., :k]

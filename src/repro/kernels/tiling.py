@@ -15,6 +15,8 @@ pass can never disagree on what "fits".
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import Tuple
 
 import jax
@@ -62,6 +64,53 @@ def pad_axis(x: jax.Array, axis: int, to: int, value) -> jax.Array:
     return jnp.pad(x, pads, constant_values=value)
 
 
+#: bytes of input a blocked pad copies per step (``pad_rows_blocked``)
+PAD_BLOCK_BYTES = 64 * 1024 * 1024
+
+
+def pad_rows_blocked(x: jax.Array, to: int, value, *,
+                     block_bytes: int = PAD_BLOCK_BYTES) -> jax.Array:
+    """``pad_axis(x, 1, to, value)`` one block of leading rows at a time.
+
+    A one-shot ``jnp.pad`` of a posting-list tensor lowers on the TPU
+    with a temporary as large as its input (4.4 GB beside a 6.4 GB
+    output for 2^14 lists of 84 x 768 f32, compiled for v5e), which
+    does not fit beside the lists it copies.  Filling the output in
+    place one block of at most ``block_bytes`` of input at a time keeps
+    the temporary to one block.
+    """
+    if x.shape[1] == to or x.shape[0] == 0:
+        return x
+    return _pad_rows_blocked(x, to, value, block_bytes=block_bytes)
+
+
+@functools.partial(jax.jit, static_argnames=("to", "block_bytes"))
+def _pad_rows_blocked(x: jax.Array, to: int, value, *,
+                      block_bytes: int) -> jax.Array:
+    n = x.shape[0]
+    row_bytes = math.prod(x.shape[1:]) * x.dtype.itemsize
+    block = max(1, min(n, block_bytes // max(row_bytes, 1)))
+    out = jnp.full((n, to) + x.shape[2:], value, x.dtype)
+    zeros = (0,) * (x.ndim - 1)
+
+    def body(i, out):
+        # the last block may overlap the one before it: it rewrites
+        # those rows with the same values
+        start = jnp.minimum(i * block, n - block)
+        blk = jax.lax.dynamic_slice_in_dim(x, start, block, 0)
+        return jax.lax.dynamic_update_slice(out, blk, (start,) + zeros)
+
+    return jax.lax.fori_loop(0, -(-n // block), body, out)
+
+
+def list_len(lmax: int) -> int:
+    """Posting-list length the list kernels stream in place: the
+    power-of-two length ``list_tile`` pads to.  A list axis already this
+    long is scanned without a per-call copy (``list_tile`` pads further
+    only when the merge depth ``kp`` exceeds it)."""
+    return next_pow2(lmax)
+
+
 def list_tile(lmax: int, row_bytes: int, *, kp: int = 1,
               max_tile: int = 2048) -> Tuple[int, int]:
     """Split a streamed posting-list axis: ``(blk_l, lpad)``.
@@ -73,7 +122,7 @@ def list_tile(lmax: int, row_bytes: int, *, kp: int = 1,
     over-allocates at large d: d=1024 f32 → 8 MiB tile → 16 MiB in
     flight).  ``lpad`` is ``lmax`` rounded up to a ``blk_l`` multiple.
     """
-    lpad = next_pow2(lmax)
+    lpad = list_len(lmax)
     blk_l = min(lpad, max_tile)
     blk_l = min(blk_l, pow2_floor(VMEM_TILE_BYTES // max(row_bytes, 1)))
     blk_l = max(blk_l, kp)

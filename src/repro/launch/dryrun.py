@@ -33,7 +33,6 @@ from typing import Any, Dict
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import common as cc
 
 from repro.configs import get as get_arch, list_archs
@@ -63,7 +62,7 @@ def lower_and_compile(bundle: cc.StepBundle, mesh):
     # `with mesh:` backs PartitionSpec-based sharding constraints;
     # jax.set_mesh additionally backs shard_map with mesh=None (the
     # distributed top-k serving paths)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with mesh:
             lowered = jitted.lower(*bundle.arg_structs)
             compiled = lowered.compile()

@@ -17,6 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import hnsw as HN
 from repro.core import ivf as IV
 from repro.data import synthetic as SY
@@ -43,6 +44,7 @@ def main() -> int:
     ap.add_argument("--turns", type=int, default=8)
     ap.add_argument("--shift-prob", type=float, default=0.1)
     args = ap.parse_args()
+    compile_cache.enable()
 
     print(f"[serve] building workload: {args.n_docs} docs, "
           f"{args.conversations}x{args.turns} turns")

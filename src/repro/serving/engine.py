@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import backend as _backend
 from repro.core import hnsw as _hnsw
@@ -202,9 +203,7 @@ class _EngineBase(_EngineAccounting):
         # mutations and compaction run on the unsharded index, then the
         # result is re-placed on the mesh
         inner_plain, index_plain = self.backend, self.index
-        if mesh is not None:
-            self.backend, self.index = _retrieval.shard_backend(
-                mesh, self.backend, self.index, axis=config.shard_axis)
+        self.backend, self.index = self._place(self.backend, self.index)
         # corpus epoch: bumped on every successful mutation (add /
         # delete / compact); cache invalidation and corpus refresh key
         # off it, and readers can use it to detect staleness
@@ -220,6 +219,23 @@ class _EngineBase(_EngineAccounting):
                 self._seg_host, base_dev=self.index)
         self.turn_count: Dict[str, int] = {}
         self.records: List[TurnRecord] = []
+
+    def _place(self, backend, index):
+        """Put ``index`` on the corpus mesh: returns (backend', index').
+
+        A one-device mesh (the router's one-chip replicas) places the
+        index on that device and keeps the single-device program; a
+        wider mesh shards it and plugs in the sharded scan.  The
+        backend lays the index out for its kernels (``backend.place``)
+        on the device it is served from."""
+        if self.mesh is None:
+            return backend, backend.place(index)
+        if self.mesh.size == 1:
+            return backend, backend.place(jax.device_put(
+                index, NamedSharding(self.mesh, PartitionSpec())))
+        return _retrieval.shard_backend(self.mesh, backend,
+                                        backend.place(index),
+                                        axis=self.cfg.shard_axis)
 
     @property
     def _sessioned(self) -> bool:
@@ -262,14 +278,9 @@ class _EngineBase(_EngineAccounting):
         seg = self._seg_host
         base_dev = self.index.base
         if base_changed:
-            base_dev = seg.base
-            if self.mesh is not None:
-                # re-place through the sharding registry (same plugin,
-                # new arrays); the returned backend is discarded — the
-                # serving backend already carries the sharded scan
-                _, base_dev = _retrieval.shard_backend(
-                    self.mesh, self._seg_inner, seg.base,
-                    axis=self.cfg.shard_axis)
+            # re-place (same plugin, new arrays); the returned backend
+            # is discarded — the serving backend already carries it
+            _, base_dev = self._place(self._seg_inner, seg.base)
         self.index = self._placed_segment(seg, base_dev=base_dev)
         self.corpus_epoch += 1
         if self._cache is not None:

@@ -48,6 +48,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+import jax
 
 from repro.concurrency import guarded_by
 from repro.distributed import retrieval as _retrieval
@@ -73,7 +74,8 @@ class ReplicatedSearchEngine:
     (split into per-replica submeshes; its replica count must match
     ``replicas``); with ``config.shards > 1`` and no mesh the 2-D mesh
     is built from the local devices; otherwise each replica runs
-    unsharded on the default device.  Engine kwargs (slots, batching)
+    unsharded on a device of its own, or, with fewer devices than
+    replicas, all of them on the default device.  Engine kwargs (slots, batching)
     apply per replica — total session capacity is ``replicas *
     n_slots``, which is the capacity story behind fig7: a session
     population that thrashes one replica's LRU slab sits fully resident
@@ -138,6 +140,13 @@ class ReplicatedSearchEngine:
         if config.shards and config.shards > 1:
             mesh = _retrieval.retrieval_mesh(
                 config.shards, axis=config.shard_axis, replicas=replicas)
+            return _retrieval.replica_submeshes(mesh)
+        if 1 < replicas <= jax.device_count():
+            # one device per replica: replica r's index, session slab
+            # and result cache live on device r (a one-device submesh
+            # places, it does not shard)
+            mesh = _retrieval.retrieval_mesh(
+                1, axis=config.shard_axis, replicas=replicas)
             return _retrieval.replica_submeshes(mesh)
         return [None] * replicas
 

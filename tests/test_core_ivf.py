@@ -1,5 +1,7 @@
 """Core IVF + k-means invariants."""
 import numpy as np
+import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import ivf, kmeans
@@ -72,3 +74,92 @@ def test_kmeans_balance_respects_capacity(rng):
     # every point assigned to a real cluster
     assert np.all(np.asarray(res.assignment) >= 0)
     assert np.all(np.asarray(res.assignment) < 8)
+
+
+# ---------------------------------------------------------------------------
+# blocked balance_assignment == the dense (n, p) formulation it replaced
+# ---------------------------------------------------------------------------
+
+
+def _dense_balance_assignment(points, centroids, p, capacity, n_choices=4):
+    """The previous formulation, kept as the reference: the full (n, p)
+    similarity matrix and an (n, p+1) one-hot cumsum per choice rank."""
+    n = points.shape[0]
+    n_choices = min(n_choices, p)
+    sims = points @ centroids.T
+    choice_sims, choice_ids = jax.lax.top_k(sims, n_choices)
+    assignment = jnp.full((n,), -1, jnp.int32)
+    sizes = jnp.zeros((p,), jnp.int32)
+    for r in range(n_choices):
+        cand = choice_ids[:, r]
+        want = assignment < 0
+        order = jnp.argsort(jnp.where(want, -choice_sims[:, r], jnp.inf))
+        cand_o, want_o = cand[order], want[order]
+        onehot_pos = jnp.cumsum(jax.nn.one_hot(
+            jnp.where(want_o, cand_o, p), p + 1, dtype=jnp.int32), axis=0)
+        pos = jnp.take_along_axis(
+            onehot_pos, jnp.where(want_o, cand_o, p)[:, None], axis=1)[:, 0] - 1
+        room = capacity - sizes[jnp.where(want_o, cand_o, 0)]
+        ok = want_o & (pos < room)
+        new_assign = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.where(ok, cand_o, -1))
+        placed = jnp.zeros((n,), bool).at[order].set(ok)
+        assignment = jnp.where(placed, new_assign, assignment)
+        sizes = sizes + jax.ops.segment_sum(
+            placed.astype(jnp.int32), jnp.where(placed, assignment, p),
+            num_segments=p + 1)[:p]
+    assignment, sizes = np.array(assignment), np.array(sizes)
+    for i in range(n):
+        if assignment[i] < 0:
+            tgt = int(np.argmin(sizes))
+            assignment[i] = tgt
+            sizes[tgt] += 1
+    return assignment, sizes
+
+
+@pytest.mark.parametrize("n,p,capacity,block", [
+    (2000, 32, 82, 4096),     # the conftest fixture's build
+    (2000, 32, 82, 256),      # several similarity blocks
+    (1000, 16, 64, 333),      # ragged last block
+    (1000, 16, 40, 4096),     # capacity short: stragglers hit the fallback
+    (500, 64, 9, 128),
+])
+def test_blocked_balance_matches_dense(small_corpus, n, p, capacity, block):
+    x = jnp.asarray(small_corpus.doc_vecs[:n])
+    c, _ = kmeans.kmeans_fit(x, p, iters=3, key=jax.random.PRNGKey(1))
+    a, s = kmeans.balance_assignment(x, c, p, capacity, block=block)
+    ra, rs = _dense_balance_assignment(x, c, p, capacity)
+    np.testing.assert_array_equal(np.asarray(a), ra)
+    np.testing.assert_array_equal(np.asarray(s), rs)
+
+
+def test_blocked_inertia_matches_dense(small_corpus):
+    x = jnp.asarray(small_corpus.doc_vecs)
+    c, _ = kmeans.kmeans_fit(x, 32, iters=3, key=jax.random.PRNGKey(1))
+    dense = jnp.mean(jnp.max(x @ c.T, axis=-1))
+    assert float(kmeans.mean_max_similarity(x, c, block=300)) == \
+        float(dense)
+
+
+def test_build_matches_dense_formulation(small_corpus, ivf_index):
+    """The fixture index equals the one the dense balancing pass and the
+    per-document bucketing loop built: same centroids, same lists in the
+    same order, pad slots masked."""
+    x = jnp.asarray(small_corpus.doc_vecs)
+    n, p = x.shape[0], ivf_index.p
+    c, _ = kmeans.kmeans_fit(x, p, iters=5, key=jax.random.PRNGKey(0))
+    cap = max(1, int(1.3 * n / p + 0.9999))
+    assign, sizes = _dense_balance_assignment(x, c, p, cap)
+    lmax = int(sizes.max())
+    ids = np.full((p, lmax), -1, np.int32)
+    fill = np.zeros(p, np.int64)
+    for doc, cl in enumerate(assign):
+        ids[cl, fill[cl]] = doc
+        fill[cl] += 1
+    vecs = np.where((ids >= 0)[..., None],
+                    np.asarray(x)[np.maximum(ids, 0)], 0.0)
+    np.testing.assert_array_equal(np.asarray(ivf_index.centroids),
+                                  np.asarray(c))
+    np.testing.assert_array_equal(np.asarray(ivf_index.list_sizes), sizes)
+    np.testing.assert_array_equal(np.asarray(ivf_index.list_ids), ids)
+    np.testing.assert_array_equal(np.asarray(ivf_index.list_vecs), vecs)

@@ -224,6 +224,33 @@ def test_fused_sessioned_start_step_bit_identical(fused_setup, name,
     _eq_stats(st1a, st1b, name + " step")
 
 
+@pytest.mark.parametrize("n_docs", [1197, 1200])
+def test_fused_pq_kernel_serves_any_corpus_size(fused_setup, n_docs):
+    """The fused PQ kernel re-ranks from whole 8-row corpus tiles.
+    Placing the index pads its lists to the kernel length and its corpus
+    rows to whole tiles once; a corpus of any size then serves through
+    the kernel (interpret mode) with the XLA path's ids."""
+    from repro.data import synthetic as SY
+    from repro.kernels import tiling
+    wl = SY.make_workload(SY.WorkloadConfig(
+        n_docs=n_docs, d=32, n_topics=12, n_conversations=3,
+        turns_per_conversation=5, seed=3))
+    x = jnp.asarray(wl.doc_vecs)
+    idx = ivf.build(x, p=24, iters=4, key=jax.random.PRNGKey(0))
+    pqi = pq.build_ivf_pq(idx, x, m=8, iters=4, key=jax.random.PRNGKey(0))
+    q = fused_setup[2]
+    base = IVFPQBackend(h=16, nprobe=4, rerank=32)
+    fb = dataclasses.replace(
+        base, fused=toploc.FusedTurn(mode="interpret"))
+    placed = fb.place(pqi)
+    assert placed.doc_vecs.shape[0] % 8 == 0
+    assert placed.lmax == tiling.list_len(pqi.lmax)
+    assert base.place(pqi) is pqi          # the XLA path keeps the build
+    _, i0, _ = base.plain_batch(pqi, q, k=K)
+    _, i1, _ = fb.plain_batch(placed, q, k=K)
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+
+
 # ----------------------------------------------------------- sharded level
 
 @pytest.mark.parametrize("shards",
@@ -242,3 +269,28 @@ def test_fused_sharded_bit_identical(fused_setup, name, base, shards):
     np.testing.assert_array_equal(np.asarray(single[0]), np.asarray(v))
     np.testing.assert_array_equal(np.asarray(single[1]), np.asarray(i))
     _eq_stats(single[2], st, f"sharded {name} s={shards}")
+
+
+@pytest.mark.parametrize("platform,kind,flops,hbm_bw", [
+    ("tpu", "TPU v5 lite", 1.97e14, 8.19e11),   # published v5e peaks
+    ("cpu", "cpu", None, None),
+    ("tpu", "TPU v99", None, None),             # unknown: no guessed peak
+])
+def test_device_model_is_keyed_by_device_kind(monkeypatch, platform, kind,
+                                              flops, hbm_bw):
+    from repro.kernels import autotune
+
+    class Dev:
+        pass
+
+    dev = Dev()
+    dev.platform, dev.device_kind = platform, kind
+    monkeypatch.setattr(autotune.jax, "devices", lambda: [dev])
+    if kind == "TPU v99":
+        with pytest.raises(ValueError, match="TPU v99"):
+            autotune.device_model()
+    elif platform == "cpu":
+        assert autotune.device_model() is autotune.CPU_MODEL
+    else:
+        hw = autotune.device_model()
+        assert (hw.flops, hw.hbm_bw) == (flops, hbm_bw)

@@ -4,9 +4,11 @@ The paper's speedup claims reduce to the work counters; an optimization
 that silently changes them (scans a different number of lists, skips the
 drift check, re-ranks a different depth) would invalidate the reported
 accounting even if retrieval quality looks fine.  This test pins the
-*exact* per-turn counters of a fixed-seed 8-turn conversation on IVF,
-IVF-PQ and HNSW so any such change fails loudly and must be justified in
-review.
+per-turn counters of a fixed-seed 8-turn conversation on IVF, IVF-PQ
+and HNSW so any such change fails loudly and must be justified in
+review.  On the IVF family it pins what k-means float output cannot
+move (centroid work, the refresh schedule, the re-rank depth) and
+checks the rest by identity against the selections the run made.
 
 The pinned values also encode the PQ cost-model identity: TopLoc_IVFPQ
 pays the same centroid work and the same |I0| refresh schedule as float
@@ -33,20 +35,20 @@ IVF_BK = IVFBackend(h=H, nprobe=NPROBE, alpha=ALPHA)
 PQ_BK = IVFPQBackend(h=H, nprobe=NPROBE, alpha=ALPHA, rerank=RERANK)
 HNSW_BK = HNSWBackend(ef=EF, up=UP)
 
+# The pinned IVF-family counters are the ones k-means float output does
+# not move: centroid work and the refresh schedule.  The list work and
+# |I0| depend on the partitioning the installed jax's k-means produces,
+# so they are checked by identity instead (``_check_ivf_family``).
 GOLD_IVF = {
     "centroid_dists": [32, 16, 16, 16, 16, 48, 16, 16],
-    "list_dists": [161, 149, 149, 168, 184, 206, 220, 212],
     "graph_dists": [0, 0, 0, 0, 0, 0, 0, 0],
     "code_dists": [0, 0, 0, 0, 0, 0, 0, 0],
-    "i0": [-1, 3, 3, 3, 3, 1, 3, 3],
     "refreshed": [1, 0, 0, 0, 0, 1, 0, 0],
 }
 GOLD_IVF_PQ = {
     "centroid_dists": [32, 16, 16, 16, 16, 48, 16, 16],
     "list_dists": [32, 32, 32, 32, 32, 32, 32, 32],      # = RERANK
     "graph_dists": [0, 0, 0, 0, 0, 0, 0, 0],
-    "code_dists": [161, 149, 149, 168, 184, 206, 220, 212],
-    "i0": [-1, 3, 3, 3, 3, 1, 3, 3],
     "refreshed": [1, 0, 0, 0, 0, 1, 0, 0],
 }
 GOLD_HNSW = {
@@ -80,16 +82,49 @@ def _check(stats: toploc.TurnStats, gold: dict) -> None:
         assert got == expect, (field, got, expect)
 
 
+def _selections(bk, index, conv) -> np.ndarray:
+    """The probe selection of every turn, re-derived from the session
+    state the sequential drivers hand back: a refreshed turn probes its
+    new anchor, any other turn the top-nprobe of its cached centroids."""
+    sels = []
+    _, _, sess, _ = toploc.start(bk, index, conv[0], k=K)
+    sels.append(np.asarray(sess.anchor_sel))
+    for q in conv[1:]:
+        _, _, sess, st = toploc.step(bk, index, sess, q, k=K)
+        if bool(st.refreshed):
+            sels.append(np.asarray(sess.anchor_sel))
+        else:
+            _, loc = jax.lax.top_k(sess.cache_vecs @ q, NPROBE)
+            sels.append(np.asarray(sess.cache_ids)[np.asarray(loc)])
+    return np.stack(sels)
+
+
+def _check_ivf_family(stats: toploc.TurnStats, bk, index, conv,
+                      field: str) -> None:
+    """Counter identities that hold for any partitioning: the scanned
+    list work is the real size of the probed lists, and a follow-up turn
+    refreshes exactly when |I0| < alpha·nprobe."""
+    sizes = np.asarray(index.list_sizes)
+    want = sizes[_selections(bk, index, conv)].sum(axis=1)
+    np.testing.assert_array_equal(np.asarray(getattr(stats, field)), want)
+    i0 = np.asarray(stats.i0)
+    assert i0[0] == -1
+    np.testing.assert_array_equal(np.asarray(stats.refreshed)[1:],
+                                  i0[1:] < ALPHA * NPROBE)
+
+
 def test_golden_ivf_counters(golden_setup):
     conv, fidx, _, _ = golden_setup
     _, _, st = toploc.conversation(IVF_BK, fidx, conv, k=K)
     _check(st, GOLD_IVF)
+    _check_ivf_family(st, IVF_BK, fidx, conv, "list_dists")
 
 
 def test_golden_ivf_pq_counters(golden_setup):
     conv, _, pqi, _ = golden_setup
     _, _, st = toploc.conversation(PQ_BK, pqi, conv, k=K)
     _check(st, GOLD_IVF_PQ)
+    _check_ivf_family(st, PQ_BK, pqi, conv, "code_dists")
 
 
 def test_golden_hnsw_counters(golden_setup):
@@ -110,6 +145,12 @@ def test_golden_fused_counters_equal_classic(golden_setup, name, bk,
     fbk = dataclasses.replace(bk, fused=toploc.FusedTurn())
     _, _, st = toploc.conversation(fbk, index, conv, k=K)
     _check(st, gold)
+    _check_ivf_family(st, bk, index, conv,
+                      "list_dists" if name == "ivf" else "code_dists")
+    _, _, st_classic = toploc.conversation(bk, index, conv, k=K)
+    for f in toploc.TurnStats._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(st, f)),
+                                      np.asarray(getattr(st_classic, f)))
 
 
 def test_golden_pq_cost_identity(golden_setup):

@@ -4,7 +4,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref, sorting
+from repro.kernels import ops, ref, sorting, tiling
 from repro.kernels import flash_attention as fa
 
 
@@ -33,6 +33,30 @@ def test_bitonic_merge(k):
         jnp.asarray(b.copy()), jnp.ones((3, k), jnp.int32))
     expect = -np.sort(-np.concatenate([a, b], -1))[:, :k]
     np.testing.assert_allclose(np.asarray(mv), expect)
+
+
+@pytest.mark.parametrize("shape", [(1,), (8,), (3, 16), (2, 4, 128)])
+def test_reverse_lanes_is_flip(shape):
+    """The roll-based lane reversal the merges use in place of jnp.flip
+    (Mosaic cannot lower lax.rev) moves every element exactly."""
+    x = jnp.asarray(RNG.normal(size=shape).astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(sorting.reverse_lanes(x)),
+                                  np.flip(np.asarray(x), axis=-1))
+
+
+@pytest.mark.parametrize("shape,to,block_bytes", [
+    ((37, 5, 7), 8, 1000),        # blocks of 7 rows, the last overlaps
+    ((37, 5, 7), 8, 1 << 20),     # one block
+    ((64, 84), 128, 4 * 84 * 8),  # 8 blocks of 8 rows, no overlap
+    ((3, 6), 6, 8),               # already at length: returned as is
+    ((1, 3, 2), 4, 1),            # one row per block
+])
+def test_pad_rows_blocked_is_pad(shape, to, block_bytes):
+    """The blocked placement pad writes exactly what jnp.pad does."""
+    x = jnp.asarray(RNG.normal(size=shape).astype(np.float32))
+    got = tiling.pad_rows_blocked(x, to, -1.0, block_bytes=block_bytes)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(tiling.pad_axis(x, 1, to, -1.0)))
 
 
 # ---------------------------------------------------------- centroid_topk

@@ -135,6 +135,21 @@ def test_least_loaded_pinning_spreads_sessions(small_corpus, ivf_index):
         assert sum(eng.load_stats()["per_replica_sessions"]) == 3
 
 
+@pytest.mark.skipif(jax.device_count() < 4,
+                    reason="one device per replica needs >= 4 devices")
+def test_unsharded_replica_state_lives_on_its_own_device(ivf_index):
+    """shards <= 1: replica r's index, session slab and result cache
+    are all on device r, not piled onto the default device."""
+    cfg = _cfg(cache_threshold=0.95, cache_depth=8)
+    with ReplicatedSearchEngine(cfg, replicas=4, ivf_index=ivf_index,
+                                n_slots=8, max_batch=4) as eng:
+        for r, e in enumerate(eng.engines):
+            want = {jax.devices()[r]}
+            for tree in (e.index, e.store._slab, e._cache._slab._slab):
+                for leaf in jax.tree.leaves(tree):
+                    assert leaf.devices() == want, (r, leaf.devices())
+
+
 def test_replicas_must_match_prebuilt_mesh(ivf_index):
     mesh = R.retrieval_mesh(1)          # 1-D: one replica group
     cfg = _cfg(mesh=mesh)
