@@ -1,0 +1,3 @@
+"""Share of the traced window in which no operation ran on the device, in
+the overload cell, %."""
+from chipbench.reduce import device_idle_share as read  # noqa: F401
