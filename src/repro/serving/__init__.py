@@ -10,7 +10,7 @@ stateless traffic).  ``scheduler`` supplies the batching/hedging
 front door.
 """
 from repro.serving import (  # noqa: F401
-    engine, result_cache, router, scheduler, sessions)
+    engine, result_cache, router, scheduler, sessions, telemetry)
 from repro.serving.engine import (  # noqa: F401
     BatchedConversationalSearchEngine, ConversationalSearchEngine,
     ServingConfig, TurnRecord)
@@ -22,3 +22,4 @@ from repro.serving.scheduler import (  # noqa: F401
 from repro.serving.sessions import (  # noqa: F401
     SessionStore, hnsw_session_store, ivf_pq_session_store,
     ivf_session_store, store_for_backend)
+from repro.serving.telemetry import Telemetry  # noqa: F401

@@ -62,6 +62,7 @@ from repro.core import toploc
 from repro.distributed import retrieval as _retrieval
 from repro.serving import result_cache as _result_cache
 from repro.serving import sessions as _sessions
+from repro.serving import telemetry as _telemetry
 from repro.serving.scheduler import MicroBatcher, Request
 
 
@@ -120,7 +121,11 @@ class ServingConfig:
 class TurnRecord:
     conv_id: str
     turn: int
-    latency_s: float              # service time (dispatch -> result) only
+    # sequential engine: the whole turn, cache lookup to result.
+    # Batched engine: from the end of the turn's wave's launch to the end
+    # of its retirement, which follows the next launch (max_inflight=2)
+    # or an idle tick's sync; it starts after the queue wait below ends
+    latency_s: float
     centroid_dists: int
     list_dists: int
     graph_dists: int
@@ -128,12 +133,14 @@ class TurnRecord:
     i0: int
     code_dists: int = 0           # PQ ADC evaluations (ivf_pq backend)
     cache_hit: bool = False       # answered from the result cache
-    # time spent queued before dispatch (batched engine; 0 for the
-    # sequential engine, which has no queue).  latency_s + queue_wait_s
-    # is the client-observed enqueue->result request latency — kept as a
-    # separate field so sequential-vs-batched latency comparisons
-    # (table1/fig3) compare service time to service time
+    # batched engine: from enqueue to the end of the turn's wave's
+    # launch, so it holds the launch's host time and every earlier wave
+    # of the same drain; 0 for the sequential engine, which has no
+    # queue.  latency_s + queue_wait_s is enqueue -> result
     queue_wait_s: float = 0.0
+    # the batched engine's wave id (the ``wave`` of its telemetry
+    # spans); -1 for the sequential engine
+    wave: int = -1
 
 
 class _EngineAccounting:
@@ -507,6 +514,14 @@ class BatchedConversationalSearchEngine(_EngineBase):
                                     max_batch=max_batch,
                                     max_wait_s=max_wait_s, buckets=buckets,
                                     max_inflight=max_inflight)
+        # spans of the serving loop (serving.telemetry); None is off.
+        # Waves are numbered from 0 at launch
+        self.telemetry: Optional[_telemetry.Telemetry] = None
+        self._next_wave = 0
+
+    def set_telemetry(self, tel: Optional[_telemetry.Telemetry]) -> None:
+        """Record this engine's serving loop into ``tel`` (None: off)."""
+        self.telemetry = self.batcher.telemetry = tel
 
     # -- public API ---------------------------------------------------
 
@@ -628,73 +643,89 @@ class BatchedConversationalSearchEngine(_EngineBase):
         stream and return immediately.  The closure's ``device_get``
         calls are the only blocking point — deferred until the batcher
         retires this launch, by which time the next wave's host assembly
-        has already overlapped this wave's device execution.
+        has already overlapped this wave's device execution.  (On a TPU
+        the eager session gather still waits on the device: PERF.md §5.)
         """
-        cfg = self.cfg
-        b = len(wave)
-        bb = self.batcher.bucket(b)          # padded (bucketed) batch size
-        qs = [np.asarray(r.payload, np.float32) for _, r in wave]
-        q = jnp.asarray(np.stack(qs + [np.zeros_like(qs[0])] * (bb - b)))
+        tel = self.telemetry
+        wid = self._next_wave
+        self._next_wave += 1
+        with _telemetry.span(tel, "wave.launch", wid):
+            cfg = self.cfg
+            b = len(wave)
+            bb = self.batcher.bucket(b)          # padded (bucketed) batch size
+            with _telemetry.span(tel, "wave.assemble", wid):
+                qs = [np.asarray(r.payload, np.float32) for _, r in wave]
+                q = jnp.asarray(np.stack(qs + [np.zeros_like(qs[0])]
+                                         * (bb - b)))
 
-        hit = None
-        if not self._sessioned:
-            v, i, stats = toploc.plain_batch(self.backend, self.index, q,
-                                             k=cfg.k)
-        else:
-            # padded rows run against the trash slot with
-            # is_first=False: their zeroed trash session never trips the
-            # drift check, so the batch-wide refresh/first-turn gates
-            # stay closed on steady-state flushes (marking them first
-            # would force the full scan on every non-bucket-exact
-            # flush); the scatter writes them back to the trash row,
-            # never a live session
-            slots = np.full((bb,), self.store.trash_slot, np.int32)
-            is_first = np.zeros((bb,), bool)
-            for row, (_, r) in enumerate(wave):
-                slots[row], is_first[row] = self.store.acquire(r.conv_id)
-            sess = self.store.gather(slots)
-            v, i, new_sess, stats = toploc.step_batch(
-                self.backend, self.index, sess, q, k=self._k_fetch,
-                is_first=jnp.asarray(is_first))
-            if self._cache is not None:
-                # fused probe over the cache slab: hit rows take the
-                # cached answer, zero their work counters, and keep the
-                # pre-step session (the sequential engine skips the
-                # dispatch entirely on a hit — same observable state)
-                v, i, new_sess, stats, hit = self._cache.fuse(
-                    slots, q, v, i, sess, new_sess, stats)
-            self.store.scatter(slots, new_sess)
+            hit = None
+            if not self._sessioned:
+                with _telemetry.span(tel, "wave.step", wid):
+                    v, i, stats = toploc.plain_batch(self.backend, self.index,
+                                                     q, k=cfg.k)
+            else:
+                # padded rows run against the trash slot with
+                # is_first=False: their zeroed trash session never trips the
+                # drift check, so the batch-wide refresh/first-turn gates
+                # stay closed on steady-state flushes (marking them first
+                # would force the full scan on every non-bucket-exact
+                # flush); the scatter writes them back to the trash row,
+                # never a live session
+                slots = np.full((bb,), self.store.trash_slot, np.int32)
+                is_first = np.zeros((bb,), bool)
+                with _telemetry.span(tel, "store.acquire", wid):
+                    for row, (_, r) in enumerate(wave):
+                        slots[row], is_first[row] = self.store.acquire(
+                            r.conv_id)
+                with _telemetry.span(tel, "store.gather", wid):
+                    sess = self.store.gather(slots)
+                with _telemetry.span(tel, "wave.step", wid):
+                    v, i, new_sess, stats = toploc.step_batch(
+                        self.backend, self.index, sess, q, k=self._k_fetch,
+                        is_first=jnp.asarray(is_first))
+                if self._cache is not None:
+                    # fused probe over the cache slab: hit rows take the
+                    # cached answer, zero their work counters, and keep the
+                    # pre-step session (the sequential engine skips the
+                    # dispatch entirely on a hit — same observable state)
+                    v, i, new_sess, stats, hit = self._cache.fuse(
+                        slots, q, v, i, sess, new_sess, stats)
+                with _telemetry.span(tel, "store.scatter", wid):
+                    self.store.scatter(slots, new_sess)
 
-        # turn numbers are claimed at LAUNCH: a later launch holding the
-        # same conversation must see this wave's increment even though
-        # its records are written at retirement
-        turns = []
-        for _, r in wave:
-            t = self.turn_count.get(r.conv_id, 0)
-            self.turn_count[r.conv_id] = t + 1
-            turns.append(t)
-        t_dispatch = time.perf_counter()
+            # turn numbers are claimed at LAUNCH: a later launch holding the
+            # same conversation must see this wave's increment even though
+            # its records are written at retirement
+            turns = []
+            for _, r in wave:
+                t = self.turn_count.get(r.conv_id, 0)
+                self.turn_count[r.conv_id] = t + 1
+                turns.append(t)
+            t_dispatch = time.perf_counter()
 
-        def finish(results) -> None:
-            vh = np.asarray(jax.device_get(v))
-            ih = np.asarray(jax.device_get(i))
-            st = jax.tree.map(lambda a: np.asarray(jax.device_get(a)),
-                              stats)
-            hh = None
-            if hit is not None:
-                hh = np.asarray(jax.device_get(hit))
-                self._cache.count_hits(hh, b)
-            now = time.perf_counter()
-            for row, ((j, r), turn) in enumerate(zip(wave, turns)):
-                rec = TurnRecord(
-                    r.conv_id, turn, now - t_dispatch,
-                    int(st.centroid_dists[row]),
-                    int(st.list_dists[row]),
-                    int(st.graph_dists[row]),
-                    bool(st.refreshed[row]), int(st.i0[row]),
-                    int(st.code_dists[row]),
-                    cache_hit=bool(hh[row]) if hh is not None else False,
-                    queue_wait_s=t_dispatch - r.enqueue_t)
-                self.records.append(rec)
-                results[j] = (vh[row], ih[row])
-        return finish
+            def finish(results) -> None:
+                with _telemetry.span(tel, "wave.fetch", wid):
+                    vh = np.asarray(jax.device_get(v))
+                    ih = np.asarray(jax.device_get(i))
+                    st = jax.tree.map(lambda a: np.asarray(jax.device_get(a)),
+                                      stats)
+                    hh = None
+                    if hit is not None:
+                        hh = np.asarray(jax.device_get(hit))
+                        self._cache.count_hits(hh, b)
+                with _telemetry.span(tel, "wave.records", wid):
+                    now = time.perf_counter()
+                    for row, ((j, r), turn) in enumerate(zip(wave, turns)):
+                        rec = TurnRecord(
+                            r.conv_id, turn, now - t_dispatch,
+                            int(st.centroid_dists[row]),
+                            int(st.list_dists[row]),
+                            int(st.graph_dists[row]),
+                            bool(st.refreshed[row]), int(st.i0[row]),
+                            int(st.code_dists[row]),
+                            cache_hit=bool(hh[row]) if hh is not None
+                            else False,
+                            queue_wait_s=t_dispatch - r.enqueue_t, wave=wid)
+                        self.records.append(rec)
+                        results[j] = (vh[row], ih[row])
+            return finish

@@ -54,6 +54,7 @@ from repro.concurrency import guarded_by
 from repro.distributed import retrieval as _retrieval
 from repro.serving.engine import (BatchedConversationalSearchEngine,
                                   ServingConfig, _EngineAccounting)
+from repro.serving import telemetry as _telemetry
 from repro.serving.scheduler import HedgedExecutor
 
 
@@ -309,7 +310,8 @@ class ReplicatedSearchEngine:
             # retires in-flight launches so tail futures resolve even
             # when no new traffic pushes them out
             if eng.flush() == 0:
-                eng.sync()
+                with _telemetry.span(eng.telemetry, "pump.sync"):
+                    eng.sync()
 
     def close(self) -> None:
         """Quiesce and tear down.  Order matters: the hedge front pool
@@ -332,6 +334,11 @@ class ReplicatedSearchEngine:
             t.join(timeout=10.0)
         for eng in self.engines:
             eng.close()
+
+    def set_telemetry(self, tel: Optional[_telemetry.Telemetry]) -> None:
+        """Record every replica's serving loop into ``tel`` (None: off)."""
+        for eng in self.engines:
+            eng.set_telemetry(tel)
 
     def __enter__(self) -> "ReplicatedSearchEngine":
         return self

@@ -46,6 +46,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 import numpy as np
 
 from repro.concurrency import guarded_by, holds
+from repro.serving import telemetry as _telemetry
 
 
 @dataclasses.dataclass
@@ -112,6 +113,9 @@ class MicroBatcher:
             collections.deque()
         self.batch_sizes: List[int] = []
         self.padded_sizes: List[int] = []
+        # spans of the drain and retire path (serving.telemetry); None
+        # is off
+        self.telemetry: Optional[_telemetry.Telemetry] = None
 
     def submit(self, req: Request) -> Future:
         fut: Future = Future()
@@ -136,7 +140,7 @@ class MicroBatcher:
         """Wait (condvar, not poll) until max_batch or the deadline,
         then pop up to max_batch items."""
         deadline = time.perf_counter() + self.max_wait_s
-        with self._work:
+        with _telemetry.span(self.telemetry, "pump.drain"), self._work:
             while len(self._queue) < self.max_batch:
                 remaining = deadline - time.perf_counter()
                 if remaining <= 0:
@@ -149,16 +153,19 @@ class MicroBatcher:
     def _retire_oldest_locked(self) -> None:
         """Complete the oldest in-flight launch and resolve its futures.
         Caller holds ``_drain_lock``."""
+        tel = self.telemetry
         items, complete = self._inflight.popleft()
-        try:
-            results = complete()
-            # pads are trailing: zip over items covers exactly the real
-            # requests and drops pad results
-            for (_, fut), res in zip(items, results):
-                fut.set_result(res)
-        except BaseException as e:
-            for _, fut in items:
-                fut.set_exception(e)
+        with _telemetry.span(tel, "batch.retire"):
+            try:
+                results = complete()
+                # pads are trailing: zip over items covers exactly the
+                # real requests and drops pad results
+                with _telemetry.span(tel, "batch.resolve"):
+                    for (_, fut), res in zip(items, results):
+                        fut.set_result(res)
+            except BaseException as e:
+                for _, fut in items:
+                    fut.set_exception(e)
 
     def flush_loop_once(self) -> int:
         """Drain one micro-batch (call from the serving loop).
