@@ -23,15 +23,17 @@ path is tested against.
 
 ``BatchedConversationalSearchEngine`` — the serving-scale path: requests
 enter a ``scheduler.MicroBatcher``; each flush drains up to ``max_batch``
-requests, pads to the next shape bucket, gathers the sessions from a
-device-resident ``sessions.SessionStore`` slab, runs ONE jitted batched
-TopLoc step (``toploc.step_batch``) with an ``is_first`` mask for rows
-whose conversation has no cached state, and scatters the updated
-sessions back.  A flush containing several turns of the same
-conversation is split into consecutive waves (a later turn must observe
-the earlier turn's updated cache), so one device batch never holds a
-conversation twice.  With the result cache enabled, each wave adds one
-fused probe over the cache slab (same slot ids as the session slab);
+requests, pads to the next shape bucket, and runs ONE jitted wave
+program on the device-resident ``sessions.SessionStore`` slab
+(``sessions.step_batch``): it reads the wave's session rows and runs the
+batched TopLoc step (``toploc.step_batch``) with an ``is_first`` mask
+for rows whose conversation has no cached state; the updated sessions
+are scattered back in place.  A flush containing several turns of the
+same conversation is split into consecutive waves (a later turn must
+observe the earlier turn's updated cache), so one device batch never
+holds a conversation twice.  With the result cache enabled, the wave
+program adds one fused probe over the cache rows (same slot ids as the
+session slab);
 hit rows take the cached answer, keep their session untouched, and
 report zero backend work — exactly what the sequential engine does when
 it skips the dispatch, so the two engines stay bit-identical with the
@@ -468,8 +470,8 @@ class BatchedConversationalSearchEngine(_EngineBase):
     drains, pads, and launches wave N+1 while wave N is still running on
     device, and wave N's futures/records are resolved when the batcher
     retires it.  Correctness under overlap comes from device-stream
-    ordering through the session slab: wave N's scatter is enqueued
-    before wave N+1's gather, so a conversation appearing in consecutive
+    ordering through the session slab: wave N's write-back is enqueued
+    before wave N+1's program, so a conversation appearing in consecutive
     launches still observes its own updated state, and the wave
     invariant (one device batch never holds a conversation twice) is
     enforced per drain exactly as before.
@@ -605,8 +607,8 @@ class BatchedConversationalSearchEngine(_EngineBase):
         """MicroBatcher dispatch callback: launch a drained micro-batch.
 
         Splits the batch into waves holding at most one turn per
-        conversation (turn t+1 must gather the session state turn t
-        scattered), launches each wave's device work without blocking,
+        conversation (turn t+1 must read the session state turn t
+        wrote), launches each wave's device work without blocking,
         and returns a completion thunk that device_gets the results and
         writes the ``TurnRecord``s.  The batcher's trailing pad requests
         are dropped here — each wave re-pads itself to its own bucket
@@ -638,13 +640,15 @@ class BatchedConversationalSearchEngine(_EngineBase):
         """Enqueue one wave's device work (no host-side blocking) and
         return a ``finish(results)`` closure that materializes it.
 
-        Everything up to the returned closure is async dispatch: gather,
-        step_batch, cache fuse, and scatter all enqueue onto the device
-        stream and return immediately.  The closure's ``device_get``
-        calls are the only blocking point — deferred until the batcher
-        retires this launch, by which time the next wave's host assembly
-        has already overlapped this wave's device execution.  (On a TPU
-        the eager session gather still waits on the device: PERF.md §5.)
+        Everything up to the returned closure is async dispatch: the
+        session store's wave program (``sessions.step_batch``: the
+        wave's slab rows read, ``toploc.step_batch`` on them, the cache
+        fuse when the cache is on) and the donating write-back of the
+        rows enqueue onto the device stream and return.  The closure's
+        ``device_get`` calls are the only blocking point — deferred
+        until the batcher retires this launch, by which time the next
+        wave's host assembly has already overlapped this wave's device
+        execution.
         """
         tel = self.telemetry
         wid = self._next_wave
@@ -677,21 +681,22 @@ class BatchedConversationalSearchEngine(_EngineBase):
                     for row, (_, r) in enumerate(wave):
                         slots[row], is_first[row] = self.store.acquire(
                             r.conv_id)
-                with _telemetry.span(tel, "store.gather", wid):
-                    sess = self.store.gather(slots)
                 with _telemetry.span(tel, "wave.step", wid):
-                    v, i, new_sess, stats = toploc.step_batch(
-                        self.backend, self.index, sess, q, k=self._k_fetch,
-                        is_first=jnp.asarray(is_first))
-                if self._cache is not None:
-                    # fused probe over the cache slab: hit rows take the
-                    # cached answer, zero their work counters, and keep the
-                    # pre-step session (the sequential engine skips the
-                    # dispatch entirely on a hit — same observable state)
-                    v, i, new_sess, stats, hit = self._cache.fuse(
-                        slots, q, v, i, sess, new_sess, stats)
-                with _telemetry.span(tel, "store.scatter", wid):
-                    self.store.scatter(slots, new_sess)
+                    # with the cache on, the program also probes the cache
+                    # rows: hit rows take the cached answer, zero their
+                    # work counters, and keep the pre-step session (the
+                    # sequential engine skips the dispatch entirely on a
+                    # hit — same observable state)
+                    cache = self._cache
+                    v, i, stats, entries, hit = self.store.step(
+                        slots, self.index, q, jnp.asarray(is_first),
+                        None if cache is None else cache.gather(slots),
+                        None if cache is None else cache.corpus,
+                        step=toploc.step_batch, backend=self.backend,
+                        k=self._k_fetch,
+                        fuse=None if cache is None else cache.wave_fuse)
+                    if cache is not None:
+                        cache.scatter(slots, entries)
 
             # turn numbers are claimed at LAUNCH: a later launch holding the
             # same conversation must see this wave's increment even though

@@ -37,6 +37,7 @@ conversation can never leak its entries to the slot's next occupant.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
@@ -160,6 +161,23 @@ def fuse_wave(entries: CacheEntry, q: jax.Array, v: jax.Array,
     return v, i, sess, stats, entries, hit
 
 
+@dataclasses.dataclass(frozen=True)
+class WaveFuse:
+    """``fuse_wave`` with its static arguments bound: the optional,
+    jit-static cache part of the session store's wave program
+    (``sessions.step_batch``).  Frozen, so equal settings hit one
+    compiled program."""
+    out_k: int
+    threshold: float
+    rescore: bool
+
+    def __call__(self, entries, q, v, i, sess_old, sess_new, stats,
+                 corpus):
+        return fuse_wave(entries, q, v, i, sess_old, sess_new, stats,
+                         corpus, out_k=self.out_k,
+                         threshold=self.threshold, rescore=self.rescore)
+
+
 @guarded_by("_lock", "hits", "misses", "_entries")
 class ResultCache:
     """Per-session result cache for both serving engines.
@@ -167,7 +185,7 @@ class ResultCache:
     Sequential mode (``n_slots=None``): entries keyed by conversation id
     in a host dict (one device row each).  Batched mode: a slab of
     ``n_slots`` rows + trash slot, addressed by the engine's session
-    slot ids (``gather``/``fuse``/``scatter``); ``clear_slot`` is the
+    slot ids (``gather``/``wave_fuse``/``scatter``); ``clear_slot`` is the
     ``SessionStore`` slot-freed listener.
 
     ``corpus`` (n, d) enables historical-embedding re-scoring on hits;
@@ -220,7 +238,7 @@ class ResultCache:
 
     def count_hits(self, hit: np.ndarray, b: int) -> None:
         """Fold a wave's materialized hit mask (first ``b`` rows are
-        real requests) into the counters.  Separate from ``fuse`` so the
+        real requests) into the counters.  Separate from the fuse so the
         continuous-batching engine can defer the blocking ``device_get``
         of the mask to wave retirement instead of the launch path."""
         n_hit = int(np.asarray(hit)[:b].sum())
@@ -306,14 +324,9 @@ class ResultCache:
         """Slot-freed listener: wipe the slot's cache row."""
         self._slab.clear([slot])
 
-    def fuse(self, slots: Sequence[int], q, v, i, sess_old, sess_new,
-             stats):
-        """Batched-wave cache pass (see ``fuse_wave``); scatters the
-        selected entries back and returns (v (B,k), i (B,k), sess,
-        stats, hit (B,) ndarray)."""
-        entries = self.gather(slots)
-        v, i, sess, stats, entries, hit = fuse_wave(
-            entries, q, v, i, sess_old, sess_new, stats, self.corpus,
-            out_k=self.k, threshold=self.threshold, rescore=self.rescore)
-        self.scatter(slots, entries)
-        return v, i, sess, stats, hit
+    @property
+    def wave_fuse(self) -> WaveFuse:
+        """The batched-wave cache pass (``fuse_wave``) the session
+        store's wave program runs over ``gather``-ed entries; the engine
+        ``scatter``s the entries it returns."""
+        return WaveFuse(self.k, self.threshold, self.rescore)

@@ -12,10 +12,19 @@ a fixed slot count:
     refreshes  (S+1,)        int32
     turn       (S+1,)        int32
 
-so serving a micro-batch is: gather B rows → one jitted batched step →
-scatter B rows back.  Slot bookkeeping (conv_id → slot, free list, LRU
-eviction) is host-side Python — it is O(B) dict work per flush and never
-touches device memory.
+so serving a micro-batch is one jitted wave program (``step_batch``)
+that reads the wave's B rows and steps them, then one donating scatter
+that writes the B rows back in place.  The slab never leaves the store:
+``SessionStore.step`` hands it to the program and takes it back.  Slot
+bookkeeping (conv_id → slot, free list, LRU eviction) is host-side
+Python — it is O(B) dict work per flush and never touches device
+memory.
+
+Rows are read by one dynamic slice each, never by a gather: the TPU
+compiler lowers a gather of whole (h, d) rows (``slab[idx]``,
+``jnp.take``, ``.at[idx].get``) into a copy of the operand's column
+chunks, which moves the whole 6.45 GB slab of the CAsT configuration to
+read 32 rows of 3 MB.
 
 Slot model
   * ``n_slots`` live slots are allocated from a free list; slot ids are
@@ -42,10 +51,11 @@ only posting lists / vector rows shard.
 
 Continuous batching note: the engine launches wave N+1 before wave N's
 results are fetched.  This is safe *because* every wave chains through
-the slab on one device stream — wave N's ``scatter`` (which consumes
-the donated slab) is enqueued before wave N+1's ``gather``, so in-order
-stream execution gives wave N+1 the updated rows and donation never
-frees a buffer a pending gather still reads.
+the slab on one device stream — wave N's program and its ``scatter``
+(each consuming the donated slab) are enqueued before wave N+1's
+program, so in-order stream execution gives wave N+1 the updated rows
+and donation never frees a buffer a pending program still reads.
+Release and eviction scatters join the same chain.
 """
 from __future__ import annotations
 
@@ -58,12 +68,53 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as _P
 
 from repro.concurrency import guarded_by
 from repro.core import hnsw as _hnsw
 from repro.core import ivf as _ivf
 from repro.core import pq as _pq
+
+
+def _rows(a: jax.Array, idx: jax.Array) -> jax.Array:
+    """Rows ``idx`` ((B,) int32, B static) of ``a``, one dynamic slice
+    each, stacked: moves B rows where a gather would move the slab (see
+    the module docstring).  Slots lie in ``[0, n_slots]``, so no slice
+    is clamped."""
+    return jnp.stack([lax.dynamic_index_in_dim(a, idx[r], keepdims=False)
+                      for r in range(idx.shape[0])])
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("step", "backend", "k", "fuse"))
+def step_batch(slab: Any, idx: jax.Array, index: Any, q: jax.Array,
+               is_first: jax.Array, entries: Any = None,
+               corpus: Optional[jax.Array] = None, *, step, backend,
+               k: int, fuse=None):
+    """One wave's device work on the session slab, as one program.
+
+    Reads the rows ``idx`` of every slab field (``_rows``), runs the
+    batched TopLoc ``step`` of ``backend`` on them, and, when the result
+    cache is on, its ``fuse`` (``result_cache.WaveFuse``) over the
+    wave's cache ``entries`` and ``corpus``.  ``step`` is
+    ``toploc.step_batch`` as the engine finds it at each launch, so a
+    wrapped or replaced step (tests, ``chipbench/limits.py``'s planted
+    faults) runs from the next launch on.  The slab is donated and
+    returned untouched, so the program neither copies it nor leaves a
+    reader of it behind; ``SessionStore.step`` writes the new rows back.
+
+    Returns (slab, sessions, v, i, stats, entries, hit); ``entries`` and
+    ``hit`` are None with the cache off.
+    """
+    sess = jax.tree.map(lambda a: _rows(a, idx), slab)
+    v, i, new_sess, stats = step(backend, index, sess, q, k=k,
+                                 is_first=is_first)
+    hit = None
+    if fuse is not None:
+        v, i, new_sess, stats, entries, hit = fuse(
+            entries, q, v, i, sess, new_sess, stats, corpus)
+    return slab, new_sess, v, i, stats, entries, hit
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -83,8 +134,8 @@ class SessionStore:
     """Fixed-capacity struct-of-arrays slab of per-conversation state.
 
     Thread safety: slot bookkeeping and the slab reference are guarded
-    by an internal ``RLock`` (reentrant because ``acquire``/``release``
-    scatter through ``self.scatter`` while already holding it).  The
+    by an internal ``RLock`` (reentrant because ``acquire``/``release``/
+    ``step`` scatter through ``self.scatter`` while holding it).  The
     batched engine serializes its wave path through the MicroBatcher's
     drain lock, but ``release`` arrives on *client* threads
     (``end_conversation``) — without the store lock, a release racing a
@@ -209,6 +260,24 @@ class SessionStore:
                     "hits": self.hits}
 
     # -- device slab access -------------------------------------------
+
+    def step(self, slots: Sequence[int], *args, **kw) -> Tuple:
+        """Run one wave's program on the rows ``slots``.
+
+        ``step_batch(slab, idx, *args, **kw)`` takes the slab donated
+        and hands it back; the wave's new sessions are scattered over
+        ``slots`` (``scatter``'s rule applies) and the rest of its
+        outputs, (v, i, stats, entries, hit), returned.  The write-back
+        is ``scatter``, the one path by which every slab write (wave,
+        release, eviction) reaches the device; on a v5e the second
+        dispatch costs the wave no device time (PERF.md §6).
+        """
+        idx = jnp.asarray(np.asarray(slots, np.int32))
+        with self._lock:
+            self._slab, sessions, *out = step_batch(self._slab, idx, *args,
+                                                    **kw)
+            self.scatter(slots, sessions)
+        return tuple(out)
 
     def gather(self, slots: Sequence[int]) -> Any:
         """Session pytree batch for ``slots`` (leading dim len(slots))."""

@@ -26,9 +26,9 @@ to no one wave):
   ``wave.launch``     host time to enqueue one wave, with children
     ``wave.assemble``   stacking the queries and putting them on the device
     ``store.acquire``   slot lookup and LRU evictions' scatters
-    ``store.gather``    the slab's rows for the wave
-    ``wave.step``       dispatch of the jitted step
-    ``store.scatter``   the donating write-back of the wave's sessions
+    ``wave.step``       dispatch of the session store's wave program
+                        (slab rows read, TopLoc step) and of the
+                        donating write-back of the wave's sessions
   ``batch.retire``    retiring one launch, with children
     ``wave.fetch``      blocking reads of a wave's results
     ``wave.records``    the wave's ``TurnRecord``s

@@ -699,3 +699,69 @@ def test_clear_zeroes_many_slots_in_one_dispatch(ivf_index, monkeypatch):
     assert freed == []                       # clear() is not a hand-over
     store.release("a")
     assert freed == [slots[0]]
+
+
+# ------------------------------------------- the session store's wave program
+
+def _parent_wave(store, slots, q, is_first, bk, index):
+    """A wave as composed before the wave program: an eager ``a[idx]``
+    gather of every slab field, ``toploc.step_batch``, the donating
+    row scatter."""
+    from repro.serving import sessions as SS
+    idx = jnp.asarray(np.asarray(slots, np.int32))
+    sess = jax.tree.map(lambda a: a[idx], store.slab)
+    v, i, new, stats = toploc.step_batch(bk, index, sess, q, k=K,
+                                         is_first=jnp.asarray(is_first))
+    with store._lock:
+        store._slab = SS._scatter_slab(store._slab, idx, new)
+    return v, i, stats
+
+
+@pytest.mark.parametrize("family", ["ivf", "ivf_pq", "hnsw"])
+def test_wave_program_matches_gather_step_scatter(small_corpus, ivf_index,
+                                                   ivf_pq_index, hnsw_index,
+                                                   family):
+    """``sessions.step_batch`` run through ``SessionStore.step`` is bit
+    for bit the gather → step → scatter it replaced: scores, ids, every
+    counter and the whole slab after each wave, over waves with padded
+    trash rows, first turns, follow-ups, LRU evictions and a release."""
+    from repro.serving import sessions as SS
+    bk, index = {
+        "ivf": (IVFBackend(h=H, nprobe=NPROBE, alpha=0.3), ivf_index),
+        "ivf_pq": (IVFPQBackend(h=H, nprobe=NPROBE, alpha=0.3, rerank=32),
+                   ivf_pq_index),
+        "hnsw": (HNSWBackend(ef=EF, up=UP), hnsw_index)}[family]
+    stores = [SS.store_for_backend(bk, index, n_slots=4) for _ in range(2)]
+    old, new = stores
+    qs = np.asarray(small_corpus.conversations, np.float32)
+    # (conversation, turn) rows of each wave; 4 slots over 6
+    # conversations evict, "c2" is released between waves
+    waves = [[(0, 0), (1, 0), (2, 0)], [(0, 1), (1, 1), (2, 1), (3, 0)],
+             [(3, 1), (0, 2)], [(4, 0), (2, 2), (5, 0)],
+             [(1, 2), (4, 1), (0, 3)], [(5, 1)]]
+    for w, wave in enumerate(waves):
+        if w == 4:
+            assert old.release("c2") == new.release("c2") is not None
+        bb = 4 if len(wave) > 2 else 2
+        slots = np.full((bb,), old.trash_slot, np.int32)
+        is_first = np.zeros((bb,), bool)
+        for row, (c, _) in enumerate(wave):
+            got = [s.acquire(f"c{c}") for s in stores]
+            assert got[0] == got[1]
+            slots[row], is_first[row] = got[0]
+        q = jnp.asarray(np.stack([qs[c % len(qs), t] for c, t in wave]
+                                 + [np.zeros_like(qs[0, 0])]
+                                 * (bb - len(wave))))
+        want = _parent_wave(old, slots, q, is_first, bk, index)
+        got = new.step(slots, index, q, jnp.asarray(is_first),
+                       step=toploc.step_batch, backend=bk, k=K)
+        assert got[3] is None and got[4] is None       # cache off
+        np.testing.assert_array_equal(want[0], got[0])
+        np.testing.assert_array_equal(want[1], got[1])
+        for f in toploc.TurnStats._fields:
+            np.testing.assert_array_equal(getattr(want[2], f),
+                                          getattr(got[2], f), err_msg=f)
+        for f, a, b in zip(old.slab._fields, old.slab, new.slab):
+            np.testing.assert_array_equal(a, b, err_msg=f"wave {w}: {f}")
+    assert old.stats() == new.stats()
+    assert new.stats()["evictions"] > 0

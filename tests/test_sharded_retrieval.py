@@ -187,6 +187,7 @@ def test_sharded_batched_engine_matches_unsharded_sequential(
     bat = BatchedConversationalSearchEngine(
         ServingConfig(**base, shards=SHARD_COUNTS[-1]), ivf_index=idx8,
         ivf_pq_index=pq8, hnsw_index=hnsw8, max_batch=4, max_wait_s=1e-4)
+    placed = [a.sharding for a in jax.tree.leaves(bat.store.slab)]
     for t in range(T):
         futs = []
         for c in range(3):          # 3 rows → padded to bucket 4
@@ -199,6 +200,8 @@ def test_sharded_batched_engine_matches_unsharded_sequential(
             np.testing.assert_array_equal(sv, bv)
             np.testing.assert_array_equal(si, bi)
     assert _records_key(seq.records) == _records_key(bat.records)
+    # the wave program hands the slab back replicated over the mesh
+    assert [a.sharding for a in jax.tree.leaves(bat.store.slab)] == placed
 
 
 # --------------------------------------------------- building blocks

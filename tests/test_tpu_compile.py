@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.core import backend as B
 from repro.core import ivf, kmeans, pq, toploc
 from repro.kernels import ops, tiling
+from repro.serving import sessions
 
 HBM_BYTES = 16 * 1024 ** 3
 
@@ -61,8 +62,11 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
+def _compile(fn, *args, **static):
+    """Compile ``fn`` (jitted here unless it already is) for the chip
+    and check that it fits HBM."""
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    compiled = jitted.lower(*args, **static).compile()
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
@@ -160,6 +164,45 @@ def test_step_batch(spec, monkeypatch, family, rerank, lmax):
                  spec((BATCH,), jnp.bool_))
     if family == "ivf_pq":
         _assert_kernel(c)
+
+
+SLOTS = 2048          # the CAsT configurations' slots; the slab adds trash
+
+
+def _family(spec, family):
+    """(backend, index) as the CAsT cells serve them on the chip: the
+    XLA scan over the lists as built, or the ADC kernel over the lists
+    placed at the kernel length with R = 128."""
+    if family == "ivf":
+        return (B.IVFBackend(h=H, nprobe=NPROBE, alpha=0.1),
+                _ivf_index(spec, LMAX))
+    return (B.IVFPQBackend(h=H, nprobe=NPROBE, alpha=0.1, rerank=128),
+            _pq_index(spec, LPAD))
+
+
+@pytest.mark.parametrize("family", ["ivf", "ivf_pq"])
+def test_wave_program_moves_rows_not_the_slab(spec, monkeypatch, family):
+    """The session store's wave program at CAsT width (2049-slot slab,
+    B = 32): no gather copies the slab's column chunks, the slab is
+    aliased through (donated, not copied), and its temporaries are the
+    step's own within 256 MiB (the 32 rows are 101 MB)."""
+    monkeypatch.setattr(ops, "default_mode", lambda: "kernel")
+    bk, index = _family(spec, family)
+    slab = toploc.IVFSession(
+        spec((SLOTS + 1, H), i32), spec((SLOTS + 1, H, D), f32),
+        spec((SLOTS + 1, NPROBE), i32), spec((SLOTS + 1,), i32),
+        spec((SLOTS + 1,), i32))
+    q, first = spec((BATCH, D), f32), spec((BATCH,), jnp.bool_)
+    wave = _compile(sessions.step_batch, slab, spec((BATCH,), i32), index,
+                    q, first, step=toploc.step_batch, backend=bk, k=K)
+    alone = _compile(lambda ix, s, qq, f: toploc.step_batch(
+        bk, ix, s, qq, k=K, is_first=f), index, _session(spec), q, first)
+    assert "mini-gather" not in wave.as_text()
+    ma, ma1 = wave.memory_analysis(), alone.memory_analysis()
+    slab_bytes = sum(x.size * x.dtype.itemsize for x in slab)
+    assert ma.alias_size_in_bytes >= slab_bytes
+    assert abs(ma.temp_size_in_bytes - ma1.temp_size_in_bytes) \
+        <= 256 * 2 ** 20, (ma.temp_size_in_bytes, ma1.temp_size_in_bytes)
 
 
 def test_balance_assignment_fits_hbm(spec):
