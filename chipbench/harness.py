@@ -8,9 +8,12 @@ then the conversations live at the window's opening replay their earlier
 turns in closed batches.  Every batch shape the window can launch is
 run here, so nothing compiles inside the window.
 
-The window drives ``ReplicatedSearchEngine.submit`` with one replica:
-its pump thread is the serving loop.  Turns are sent at their scheduled
-times whatever has come back, from this process's main thread.
+The window drives ``ReplicatedSearchEngine.submit`` with the
+configuration's ``replicas`` (1 where it states none), each on a chip
+of its own where there are as many: their pump threads are the serving
+loop, and the router pins each conversation to one replica.  Turns are
+sent at their scheduled times whatever has come back, from this
+process's main thread.
 
 After the window every due turn is waited for (a minute at most), the
 device's memory is read, the engine is closed and freed, and the
@@ -24,7 +27,7 @@ import os
 import shutil
 import sys
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -48,6 +51,7 @@ class Observations:
     """What the per-layer metric readers read (``metrics/<name>.py``)."""
     config: Dict
     records: List                    # TurnRecords of the window's turns
+    replica: List[int]               # the replica that served each record
     batch_sizes: List[int]           # real rows of the window's launches
     padded_sizes: List[int]          # rows launched, padding included
     build_s: float
@@ -134,12 +138,40 @@ class CompileCounter:
             self.count += 1
 
 
+def replicas(cfg: Dict) -> int:
+    """The configuration's one-chip replicas behind the router."""
+    return int(cfg.get("replicas", 1))
+
+
+def slots(cfg: Dict) -> int:
+    """Session slots of the whole deployment: every replica's slab."""
+    return cfg["n_slots"] * replicas(cfg)
+
+
+def chips(router) -> List:
+    """The devices the router's replicas serve from, each once: a
+    replica's corpus mesh, or the default device where it has none."""
+    import jax
+    devs = [d for e in router.engines
+            for d in (e.mesh.devices.flat if e.mesh is not None
+                      else jax.devices()[:1])]
+    return list(dict.fromkeys(devs))
+
+
+def fullest(devices) -> Tuple[int, int]:
+    """(bytes in use, peak bytes in use) of the fullest of ``devices``,
+    each read on its own chip; 0 where the backend keeps no count."""
+    stats = [d.memory_stats() or {} for d in devices]
+    return (max(s.get("bytes_in_use", 0) for s in stats),
+            max(s.get("peak_bytes_in_use", 0) for s in stats))
+
+
 def inputs(cfg: Dict, traffic: Dict, seed: int, seconds: float):
     """(schedule, corpus on the device, every turn's query on the host)
     of one run."""
     import jax
-    sched = gen.schedule(traffic, seed, seconds, cfg["n_slots"])
-    rows = sched.n_convs + cfg["n_slots"] + 8     # fixed shape per cell
+    sched = gen.schedule(traffic, seed, seconds, slots(cfg))
+    rows = sched.n_convs + slots(cfg) + 8         # fixed shape per cell
     centers, docs = gen.corpus(cfg, seed)
     qdev = gen.conversations(cfg, centers, seed, rows, sched.turns,
                              traffic["shift_prob"])
@@ -178,27 +210,29 @@ def make_engine(cfg: Dict, **index_kw):
                          alpha=cfg["alpha"], rerank=cfg.get("rerank", 64),
                          cache_threshold=0.0)
     return ReplicatedSearchEngine(
-        scfg, replicas=1, n_slots=cfg["n_slots"],
+        scfg, replicas=replicas(cfg), n_slots=cfg["n_slots"],
         max_batch=cfg["max_batch"], max_wait_s=cfg["max_wait_s"],
         max_inflight=cfg["max_inflight"], **index_kw)
 
 
 def warm_up(router, sched: gen.Schedule, queries: np.ndarray,
-            max_batch: int) -> int:
-    """Set-up's closed batches: one wave of every bucket size first
-    (fillers), then the rest of the fillers and the replayed turns.
-    Returns the number of turns served."""
+            max_batch: int, replicas: int = 1) -> int:
+    """Set-up's closed batches: one wave of every bucket size on every
+    replica first (fillers, which the router pins in turn, so each of
+    ``b x replicas`` lands ``b`` on each), then the rest of the fillers
+    and the replayed turns.  Returns the number of turns served."""
     order = gen.replay_order(sched)
     served, i = 0, 0
     b = 1
     while b <= max_batch and i < len(order):
-        for c, t in order[i:i + b]:
+        for c, t in order[i:i + b * replicas]:
             router.submit(sched.conv_id(c), queries[c, t])
         served += router.drain()
-        i += b
+        i += b * replicas
         b *= 2
-    for j in range(i, len(order), max_batch):
-        for c, t in order[j:j + max_batch]:
+    step = max_batch * replicas
+    for j in range(i, len(order), step):
+        for c, t in order[j:j + step]:
             router.submit(sched.conv_id(c), queries[c, t])
         served += router.drain()
     return served
@@ -298,14 +332,16 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, *, seed: int,
     del docs
     router = make_engine(cfg, **{kw: index})
     del index
-    eng = router.engines[0]
-    warm = warm_up(router, sched, queries, cfg["max_batch"])
+    engines = router.engines
+    used = chips(router)
+    warm = warm_up(router, sched, queries, cfg["max_batch"],
+                   replicas(cfg))
     log(f"build {build_s:.3f}s; warm-up served {warm} turns; slab "
-        f"{eng.store.stats()}")
+        + "; ".join(str(e.store.stats()) for e in engines))
 
     # -- window ---------------------------------------------------------
-    r0 = len(eng.records)
-    b0 = len(eng.batcher.batch_sizes)
+    r0 = [len(e.records) for e in engines]
+    b0 = [len(e.batcher.batch_sizes) for e in engines]
     tracer = None
     if trace:
         shutil.rmtree(os.path.join(out_dir, "trace"), ignore_errors=True)
@@ -335,7 +371,7 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, *, seed: int,
     compiles.armed = pauses.armed = False
     watch.close()
     pauses.close()
-    b1 = len(eng.batcher.batch_sizes)
+    b1 = [len(e.batcher.batch_sizes) for e in engines]
     t0 = res["t0"]
     if tracer is not None:
         opened["timer"].join()
@@ -344,10 +380,9 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, *, seed: int,
     router.close()
     gc.unfreeze()
     # read once the engine is quiet: what serving keeps resident, not
-    # the temporaries of whichever wave happened to be in flight
-    stats = dev.memory_stats() or {}
-    in_use = stats.get("bytes_in_use", 0)
-    peak = stats.get("peak_bytes_in_use", 0)
+    # the temporaries of whichever wave happened to be in flight; the
+    # fullest of the cell's chips
+    in_use, peak = fullest(used)
 
     e2e = window.summary(res["due"], res["done"], seconds)
     late = res["sent"] - res["due"]
@@ -369,12 +404,22 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, *, seed: int,
         ok = f.done() and f.exception() is None
         answers.append(tuple(np.asarray(a) for a in f.result())
                        if ok else None)
-    records = eng.records[r0:]
-    obs = Observations(cfg, records, eng.batcher.batch_sizes[b0:b1],
-                       eng.batcher.padded_sizes[b0:b1], build_s, peaks,
-                       window=dict(e2e))
+    # the window's records and launches, replica by replica
+    per = [e.records[n:] for e, n in zip(engines, r0)]
+    records = [r for rs in per for r in rs]
+    obs = Observations(
+        cfg, records, [k for k, rs in enumerate(per) for _ in rs],
+        [n for e, lo, hi in zip(engines, b0, b1)
+         for n in e.batcher.batch_sizes[lo:hi]],
+        [n for e, lo, hi in zip(engines, b0, b1)
+         for n in e.batcher.padded_sizes[lo:hi]],
+        build_s, peaks, window=dict(e2e))
+    if len(engines) > 1:
+        log("window's turns by replica: "
+            + ", ".join(str(len(rs)) for rs in per))
     if tracer is not None:
-        ev = tracing.events(tracing.xplane_path(tracer.log_dir))
+        ev = tracing.events(tracing.xplane_path(tracer.log_dir),
+                            chips=len(used))
         obs.trace = tracing.reduce(ev, kernels=(ADC_KERNEL,))
         # the waves the trace holds: turns launched inside its span, on
         # the host clock (the trace's own clock starts at its opening)
@@ -386,7 +431,7 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, *, seed: int,
             r for r in records
             if lo <= sent_at.get((r.conv_id, r.turn), -1e9)
             + r.queue_wait_s <= hi]
-    del router, eng, res
+    del router, engines, per, res
     gc.collect()
 
     # -- reference, once the program's state is freed -------------------
@@ -412,15 +457,19 @@ def run_cell(cell: Dict, cfg: Dict, traffic: Dict, *, seed: int,
             metrics[m["name"]] = {"value": float(e2e[m["name"]]),
                                   "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": 1, "memory_peak_bytes": int(peak)}
+              "count": len(used), "memory_peak_bytes": int(peak)}
     out = {"correct": all(r["value"] <= r["limit"]
                           for r in readings.values()),
            "attempted": int(len(sched.window)),
            "failed": int(e2e["unanswered"]),
            "metrics": metrics, "device": device}
     if obs.trace is not None:
-        device.update(busy_s=obs.trace["busy_s"],
-                      window_s=obs.trace["window_s"])
+        # busy seconds averaged over the chips used; the traced window
+        # is the longest of theirs (one chip: its own two numbers)
+        device.update(busy_s=float(np.mean([c["busy_s"] for c in
+                                            obs.trace["chips"]])),
+                      window_s=max(c["window_s"] for c in
+                                   obs.trace["chips"]))
         out["breakdown"] = obs.trace["breakdown"]
     out["compared"] = readings
     return out

@@ -11,9 +11,6 @@ import numpy as np
 
 #: the jitted module of one engine wave, as named in the device trace
 STEP_MODULE = "jit_step_batch"
-#: the session gather's programs (one per slab field), run eagerly
-#: before each wave's step
-GATHER_MODULE = "jit_gather"
 F32_BYTES = 4
 
 
@@ -42,9 +39,11 @@ def full_scan_share(obs) -> Optional[float]:
 
 
 def _steps(obs):
+    """Device times of every chip's ``step_batch`` executions."""
     if obs.trace is None:
         return []
-    return obs.trace["modules"].get(STEP_MODULE, [])
+    return [s for c in obs.trace["chips"]
+            for s in c["modules"].get(STEP_MODULE, [])]
 
 
 def step_device_ms(obs) -> Optional[float]:
@@ -52,26 +51,30 @@ def step_device_ms(obs) -> Optional[float]:
     return float(np.mean(steps) * 1e3) if steps else None
 
 
-def gather_device_ms(obs) -> Optional[float]:
-    """Device time of the session gather per wave: the ``jit_gather``
-    executions' time over the number of ``step_batch`` executions."""
-    steps = _steps(obs)
-    if not steps:
-        return None
-    gathers = obs.trace["modules"].get(GATHER_MODULE, [])
-    return float(np.sum(gathers) / len(steps) * 1e3) if gathers else None
-
-
 def device_idle_share(obs) -> Optional[float]:
-    t = obs.trace
-    if t is None or t["window_s"] <= 0:
+    """The idle share of each chip's plane, averaged over the cell's
+    chips (one chip: its own)."""
+    chips = [c for c in (obs.trace or {}).get("chips", [])
+             if c["window_s"] > 0]
+    if not chips:
         return None
-    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
+    return 100.0 * float(np.mean([1.0 - c["busy_s"] / c["window_s"]
+                                  for c in chips]))
+
+
+def replica_imbalance(obs) -> Optional[float]:
+    """The busiest replica's turns over the mean of the configuration's
+    replicas, %: 100 is an even spread, 100 x R all on one."""
+    if not obs.records:
+        return None
+    counts = np.bincount(obs.replica, minlength=obs.config.get("replicas", 1))
+    return 100.0 * float(counts.max() / counts.mean())
 
 
 def step_roofline(obs) -> Optional[float]:
     """Least time to read the probed lists' real rows at the chip's peak
-    bandwidth over the ``step_batch`` device time, for the waves traced.
+    bandwidth over the ``step_batch`` device time of every chip, for the
+    waves traced.
     Counts rows per turn: a scan that reads one list once for several
     turns of a wave could read above 100%."""
     steps = _steps(obs)

@@ -102,7 +102,8 @@ def report(seen: Dict) -> Dict:
             f"{(s.start_ns - t_open) * 1e-9:.3f}s")
     waves = {s.wave for s in got if s.name == "wave.launch"}
     records = [r for r in seen["records"] if r.wave in waves]
-    obs = SimpleNamespace(spans=got, records=records, idle=None)
+    obs = SimpleNamespace(spans=got, records=records,
+                          replica=[0] * len(records), idle=None)
     mapped = None
     if "ev" in seen:
         ev = seen["ev"]
@@ -110,7 +111,7 @@ def report(seen: Dict) -> Dict:
         mapped = spans.clock(got, notes)
         log(f"clock: {mapped}")
         if mapped is not None:
-            obs.idle = spans.idle_table(ev["device"][tracing.OPS_LINE], got,
+            obs.idle = spans.idle_table(ev["chips"][0][tracing.OPS_LINE], got,
                                         mapped["offset_ns"])
             for name, v in sorted(obs.idle["by_span"].items(),
                                   key=lambda x: -x[1]):

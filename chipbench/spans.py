@@ -203,12 +203,13 @@ def gate_open_share(obs) -> Optional[float]:
     """Waves whose refresh gate opened over the window's waves, %: a
     wave's gate is open where any of its turns refreshed (its pad rows
     never do; a result-cache hit zeroes its row's flag, but a hit is
-    never a first turn)."""
-    gates: Dict[int, bool] = {}
-    for r in obs.records:
+    never a first turn).  Each replica numbers its own waves, so a wave
+    is its replica (``obs.replica``) and its id."""
+    gates: Dict[Tuple[int, int], bool] = {}
+    for k, r in zip(obs.replica, obs.records):
         w = getattr(r, "wave", -1)
         if w >= 0:
-            gates[w] = gates.get(w, False) or bool(r.refreshed)
+            gates[k, w] = gates.get((k, w), False) or bool(r.refreshed)
     return 100.0 * float(np.mean(list(gates.values()))) if gates else None
 
 

@@ -4,14 +4,16 @@
     python3 chipbench/sweep.py --workload <cell> --seed 5 \\
         --rates 600 900 1200 1500 --seconds 8
 
-One process: the cell's index and engine are built once, then each rate
-runs its own open-loop window (a fresh schedule of the cell's traffic
-mix at that rate, its live conversations replayed first).  A rate is
-kept up with when the turns answered inside the window reach 98% of
-those offered and the latency of the window's second half is within
-1.5x of its first half's (the backlog does not grow).  One JSON line
-per rate; the knee is the highest rate kept up with below the first
-that is not.  A traffic file takes its rate from this once, by hand.
+One process: the cell's index and engine (all of the configuration's
+replicas) are built once, then each rate runs its own open-loop window
+(a fresh schedule of the cell's traffic mix at that rate, its live
+conversations replayed first).  A rate is kept up with when the turns
+answered inside the window reach 98% of those offered and the latency
+of the window's second half is within 1.5x of its first half's (the
+backlog does not grow).  One JSON line per rate, in the order given,
+until the first that is not kept up with: the knee is the highest rate
+kept up with below it.  A traffic file takes its rate from this once,
+by hand.
 """
 from __future__ import annotations
 
@@ -59,12 +61,13 @@ def main(argv=None) -> int:
     for step, rate in enumerate(args.rates):
         t = dict(traffic, rate_turns_per_s=rate)
         sched = gen.schedule(t, args.seed + step, args.seconds,
-                             cfg["n_slots"], prefix=f"s{step}")
-        rows = sched.n_convs + cfg["n_slots"] + 8
+                             harness.slots(cfg), prefix=f"s{step}")
+        rows = sched.n_convs + harness.slots(cfg) + 8
         q = np.asarray(jax.device_get(gen.conversations(
             cfg, centers, args.seed + step, rows, sched.turns,
             t["shift_prob"])))
-        harness.warm_up(router, sched, q, cfg["max_batch"])
+        harness.warm_up(router, sched, q, cfg["max_batch"],
+                        harness.replicas(cfg))
         res = harness.drive(router, sched, q, args.seconds)
         harness.wait_all(res["futures"], res["t0"] + args.seconds + 30)
         due, done = res["due"], res["done"]
@@ -80,6 +83,8 @@ def main(argv=None) -> int:
                           "p95_first_half_ms": p95[0],
                           "p95_second_half_ms": p95[1],
                           "kept_up": kept}), flush=True)
+        if not kept:
+            break
     router.close()
     return 0
 

@@ -17,6 +17,10 @@ Numbers taken from a trace:
   kernel name (a Pallas call keeps its kernel's name);
 * the breakdown: the device operations that took most time, and the idle
   gaps by the host event that covered them.
+
+A cell on several chips has a device plane per chip: each is reduced on
+its own, against the one host plane, and the first chip's numbers stand
+at the top level as a one-chip cell's do.
 """
 from __future__ import annotations
 
@@ -87,26 +91,34 @@ def xplane_path(log_dir: str) -> str:
     return paths[-1]
 
 
-def events(path: str) -> Dict[str, Dict[str, List[Event]]]:
-    """``{"device": {line: events}, "host": {line: events}}`` of the
-    first TPU's plane and the host's plane."""
+DEVICE_PLANE = "/device:TPU:"
+
+
+def _lines(plane) -> Dict[str, List[Event]]:
+    out: Dict[str, List[Event]] = {}
+    for line in plane.lines:
+        out.setdefault(line.name, []).extend(
+            (e.name, float(e.start_ns), float(e.duration_ns))
+            for e in line.events)
+    return out
+
+
+def _device_id(name: str) -> int:
+    digits = re.match(r"\d+", name[len(DEVICE_PLANE):])
+    return int(digits.group()) if digits else 0
+
+
+def events(path: str, chips: int = 1) -> Dict:
+    """``{"chips": [{line: events}, ...], "host": {line: events}}``: the
+    first ``chips`` TPUs' planes, by device id, and the host's plane."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    out: Dict[str, Dict[str, List[Event]]] = {"device": {}, "host": {}}
-    device_done = False
-    for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:") and not device_done:
-            kind = "device"
-            device_done = True
-        elif plane.name == "/host:CPU":
-            kind = "host"
-        else:
-            continue
-        for line in plane.lines:
-            evs = [(e.name, float(e.start_ns), float(e.duration_ns))
-                   for e in line.events]
-            out[kind].setdefault(line.name, []).extend(evs)
-    return out
+    devices = [p for p in pd.planes if p.name.startswith(DEVICE_PLANE)]
+    host = [p for p in pd.planes if p.name == "/host:CPU"]
+    devices.sort(key=lambda p: _device_id(p.name))
+    planes = [_lines(p) for p in devices[:max(chips, 1)]]
+    return {"chips": planes or [{}],
+            "host": _lines(host[0]) if host else {}}
 
 
 def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -151,16 +163,26 @@ class _HostCover:
 SHORT_GAP_NS = 2_000.0
 
 
-def span(ev: Dict[str, Dict[str, List[Event]]]) -> Tuple[float, float]:
-    """(first start, last end) in ns over every event of the trace: the
-    window the profiler recorded."""
-    evs = [e for kind in ev.values() for line in kind.values() for e in line]
+def span(ev: Dict) -> Tuple[float, float]:
+    """(first start, last end) in ns over every event of the device's
+    and the host's planes: the window the profiler recorded."""
+    evs = [e for kind in ("device", "host") for line in ev[kind].values()
+           for e in line]
     return (min(s for _, s, _ in evs), max(s + d for _, s, d in evs))
 
 
-def reduce(ev: Dict[str, Dict[str, List[Event]]],
-           kernels: Tuple[str, ...] = ()) -> Dict:
-    """Numbers of one traced window."""
+def reduce(ev: Dict, kernels: Tuple[str, ...] = ()) -> Dict:
+    """Numbers of one traced window: the first chip's, and under
+    ``"chips"`` one entry per chip, each its plane's numbers."""
+    host = ev.get("host", {})
+    chips = [_reduce_chip({"device": d, "host": host}, kernels)
+             for d in ev["chips"]]
+    return dict(chips[0], chips=chips)
+
+
+def _reduce_chip(ev: Dict[str, Dict[str, List[Event]]],
+                 kernels: Tuple[str, ...]) -> Dict:
+    """Numbers of one chip's plane, against the host's."""
     dev = ev["device"]
     ops = dev.get(OPS_LINE) or []
     if not ops:

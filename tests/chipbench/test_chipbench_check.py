@@ -5,10 +5,14 @@ Runs the harness at a tiny size on the CPU: the look for a chip is
 skipped (it lives in ``run.py``), the rest of a run is driven as on the
 chip, with the configuration's own limits.
 """
+import json
+import os
 import pathlib
+import subprocess
 import sys
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -41,6 +45,9 @@ def run(cfg, seed=SEED):
 
 CONFIGS = [tiny("cast-ivf-f32"),
            tiny("cast-ivfpq-m48", pq_iters=3, rerank=32)]
+# four replicas behind the router: each on a CPU device of its own where
+# there are four or more, all on the first otherwise
+ROUTER = tiny("cast-ivf-f32-router4")
 
 
 @pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c["name"])
@@ -52,15 +59,106 @@ def test_a_sound_run_is_correct(cfg):
     assert set(out["compared"]) == {"unanswered", *cfg["limits"]}
 
 
+def served_by_four_replicas():
+    """A tiny run of the router configuration, and what it left: the
+    devices its replicas served from, the replicas each conversation met,
+    and the window's turns by the replica tag the metric readers got."""
+    made, seen = [], []
+    real_engine, real_obs = harness.make_engine, harness.Observations
+    harness.make_engine = lambda cfg, **kw: (made.append(
+        real_engine(cfg, **kw)) or made[-1])
+    harness.Observations = lambda *a, **kw: (seen.append(
+        real_obs(*a, **kw)) or seen[-1])
+    try:
+        out = run(ROUTER)
+    finally:
+        harness.make_engine, harness.Observations = real_engine, real_obs
+    (router,), (obs,) = made, seen
+    where = {}
+    for k, eng in enumerate(router.engines):
+        for r in eng.records:
+            where.setdefault(r.conv_id, set()).add(k)
+    return {"out": out, "chips": [str(d) for d in harness.chips(router)],
+            "tags": np.bincount(obs.replica, minlength=4).tolist(),
+            "imbalance": registry.reader("replica_imbalance")(obs),
+            "replicas_met": sorted({len(ks) for ks in where.values()})}
+
+
+def check_four_replicas(got, devices):
+    out = got["out"]
+    assert out["correct"], out["compared"]
+    assert out["attempted"] == 180 and out["failed"] == 0
+    # a chip of its own for each replica where there are four
+    assert out["device"]["count"] == len(got["chips"]) == min(4, devices)
+    # every window turn tagged, every replica serving some
+    assert sum(got["tags"]) == 180 and min(got["tags"]) > 0, got["tags"]
+    assert got["imbalance"] == pytest.approx(
+        100.0 * max(got["tags"]) / (180 / 4))
+    assert got["replicas_met"] == [1]
+
+
+def test_four_replicas_answer_every_turn_each_conversation_on_one():
+    check_four_replicas(served_by_four_replicas(), jax.device_count())
+
+
+FOUR_DEVICES = """
+import json, sys
+sys.path.insert(0, {here!r})
+import test_chipbench_check as t
+got = t.served_by_four_replicas()
+print(json.dumps(dict(got, devices=t.jax.device_count())))
+"""
+
+
+def test_four_replicas_on_four_devices_each_serve_from_their_own():
+    """The same run in a process that sees four CPU devices."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    here = str(pathlib.Path(__file__).parent)
+    proc = subprocess.run([sys.executable, "-c",
+                           FOUR_DEVICES.format(here=here)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["devices"] == 4
+    check_four_replicas(got, 4)
+    assert len(set(got["chips"])) == 4
+
+
+class Chip:
+    def __init__(self, in_use, peak):
+        self.stats = {"bytes_in_use": in_use, "peak_bytes_in_use": peak}
+
+    def memory_stats(self):
+        return self.stats
+
+
+def test_memory_is_read_on_the_fullest_chip():
+    # each number from the chip that holds the most of it
+    chips = [Chip(5, 9), Chip(7, 8), Chip(6, 12), Chip(1, 2)]
+    assert harness.fullest(chips) == (7, 12)
+    assert harness.fullest(chips[:1]) == (5, 9)
+
+
+class NoStats:
+    def memory_stats(self):
+        return None
+
+
+def test_a_backend_without_memory_counts_reads_nothing():
+    assert harness.fullest([NoStats(), NoStats()]) == (0, 0)
+
+
 @pytest.mark.parametrize("fault", ["state", "answer"])
-@pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c["name"])
+@pytest.mark.parametrize("cfg", CONFIGS + [ROUTER], ids=lambda c: c["name"])
 def test_a_planted_fault_is_not_correct(cfg, fault):
     with limits.planted(fault, cfg["n_docs"]):
         out = run(cfg)
     assert not out["correct"], out["compared"]
 
 
-@pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c["name"])
+@pytest.mark.parametrize("cfg", CONFIGS + [ROUTER], ids=lambda c: c["name"])
 def test_the_control_is_not_correct(cfg):
     got = limits.control_readings(cfg, TRAFFIC, SEED, 1.5)
     assert got["score_err"] > cfg["limits"]["score_err"], got

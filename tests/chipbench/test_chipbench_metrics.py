@@ -18,20 +18,22 @@ def rec(wait_s=0.001, centroid=1024, lists=4000, codes=0):
               centroid_dists=centroid, list_dists=lists, code_dists=codes)
 
 
-def obs(records=(), trace=None, traced=None, batches=(), padded=()):
-    o = Observations(CFG, list(records), list(batches), list(padded),
-                     12.5, PEAKS, trace)
+def obs(records=(), trace=None, traced=None, batches=(), padded=(),
+        config=CFG, replica=()):
+    o = Observations(config, list(records),
+                     list(replica) or [0] * len(records), list(batches),
+                     list(padded), 12.5, PEAKS, trace)
     o.traced_records = list(records if traced is None else traced)
     return o
 
 
 def trace(busy=0.4, window=1.0, steps=(0.004, 0.006), adc=0.0,
-          gathers=()):
-    mods = {"jit_step_batch": list(steps)}
-    if gathers:
-        mods["jit_gather"] = list(gathers)
-    return {"busy_s": busy, "window_s": window, "modules": mods,
-            "kernel_s": {"pq_adc": adc}}
+          chips=None):
+    out = {"busy_s": busy, "window_s": window,
+           "modules": {"jit_step_batch": list(steps)},
+           "kernel_s": {"pq_adc": adc}}
+    out["chips"] = chips if chips is not None else [dict(out)]
+    return out
 
 
 def read(name, o):
@@ -67,11 +69,27 @@ def test_step_device_ms_and_idle_share():
     assert read("device_idle_share.overload", o) == pytest.approx(87.5)
 
 
-def test_gather_device_ms_is_per_wave():
-    # two waves, each gathering the slab's five fields
-    o = obs([rec()], trace(gathers=[0.004] * 10))
-    assert read("gather_device_ms", o) == pytest.approx(20.0)
-    assert read("gather_device_ms", obs([rec()], trace())) is None
+def test_idle_share_of_four_chips_is_their_mean():
+    chips = [{"busy_s": b, "window_s": 2.0} for b in (1.0, 1.5, 1.9, 2.0)]
+    o = obs([rec()], trace(busy=1.0, window=2.0, chips=chips))
+    # (50 + 25 + 5 + 0) / 4, not chip 0's own 50
+    assert read("device_idle_share", o) == pytest.approx(20.0)
+    # one chip: its own share
+    o = obs([rec()], trace(busy=0.25, window=2.0))
+    assert read("device_idle_share", o) == pytest.approx(87.5)
+
+
+def test_replica_imbalance_is_the_busiest_over_the_mean():
+    four = dict(CFG, replicas=4)
+    o = obs([rec()] * 10, config=four, replica=[0] * 4 + [1, 1, 2, 2, 3, 3])
+    assert read("replica_imbalance", o) == pytest.approx(160.0)
+    even = obs([rec()] * 8, config=four, replica=[0, 1, 2, 3] * 2)
+    assert read("replica_imbalance", even) == pytest.approx(100.0)
+    # a replica that served nothing counts in the mean
+    idle = obs([rec()] * 4, config=four, replica=[0, 0, 1, 1])
+    assert read("replica_imbalance", idle) == pytest.approx(200.0)
+    # one replica, records not tagged
+    assert read("replica_imbalance", obs([rec()] * 3)) == pytest.approx(100.0)
 
 
 def test_step_roofline_counts_real_rows_at_peak_bandwidth():
@@ -96,9 +114,8 @@ def test_build_s():
 @pytest.mark.parametrize("name", ["turn_p95_ms", "queue_wait_p95_ms",
                                   "batch_fill.overload",
                                   "full_scan_share", "step_device_ms",
-                                  "gather_device_ms",
                                   "device_idle_share", "step_roofline",
-                                  "adc_roofline"])
+                                  "adc_roofline", "replica_imbalance"])
 def test_nothing_to_read_gives_nothing(name):
     assert read(name, obs()) is None
 

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
-from chipbench import gen  # noqa: E402
+from chipbench import gen, harness, registry  # noqa: E402
 
 CONV = {"rate_turns_per_s": 1000, "turns_min": 6, "turns_max": 12,
         "live_population": 1024, "shift_prob": 0.1}
@@ -107,3 +107,64 @@ def test_arrivals_are_poisson(seed):
     c = gen.schedule(CONV, seed, 60.0, 2048)
     firsts = c.window[c.window[:, 2] == 0, 0]
     assert 0.9 < _cv(np.diff(firsts)) < 1.1
+
+
+def test_fillers_fill_every_replicas_slab():
+    cfg = registry.config("cast-ivf-f32-router4")
+    assert harness.replicas(cfg) == 4
+    assert harness.slots(cfg) == 4 * cfg["n_slots"] == 8192
+    conv4 = dict(CONV, live_population=4096)
+    s = gen.schedule(conv4, BIG, 20.0, harness.slots(cfg))
+    # about a quarter of the four slabs live, the rest fillers, and a
+    # few more than fit, so that every replica evicts in set-up
+    assert 3200 < s.n_live < 4000
+    assert s.n_live + s.n_fill == 8192 + 8
+
+
+class Recorder:
+    """A router that keeps what set-up sends it, drain by drain."""
+
+    def __init__(self):
+        self.sent, self.drains = [], []
+
+    def submit(self, conv_id, qvec):
+        self.sent.append(conv_id)
+
+    def drain(self):
+        self.drains.append(len(self.sent) - sum(self.drains))
+        return self.drains[-1]
+
+
+def test_one_replica_runs_as_before():
+    """A configuration without ``replicas`` gets the schedule, fillers
+    and set-up order it got before replicas were taken: the schedule of
+    its own slab (these counts are what that gave for this seed), one
+    wave of each bucket size, then waves of ``max_batch``."""
+    cfg = registry.config("cast-ivf-f32")
+    assert "replicas" not in cfg and harness.replicas(cfg) == 1
+    assert harness.slots(cfg) == cfg["n_slots"] == 2048
+    traffic = registry.traffic("conv-open-500")
+    s = gen.schedule(traffic, 4200000137, 40.0, harness.slots(cfg))
+    assert (s.n_convs, s.n_fill, s.n_live) == (5977, 1107, 949)
+    assert (len(s.window), len(s.replay), s.turns) == (20000, 4474, 12)
+    assert s.window[:3, 1:].astype(int).tolist() == [[2650, 10], [3577, 2],
+                                                     [3047, 5]]
+    order = gen.replay_order(s)
+    r = Recorder()
+    served = harness.warm_up(r, s, np.zeros((s.n_convs + 2056, 12, 1)),
+                             cfg["max_batch"], harness.replicas(cfg))
+    assert served == len(order) == 5581
+    assert r.sent == [s.conv_id(c) for c, _ in order]
+    full, rest = divmod(len(order) - 63, 32)
+    assert r.drains == [1, 2, 4, 8, 16, 32] + [32] * full + [rest]
+
+
+def test_four_replicas_warm_every_bucket_on_each():
+    s = gen.schedule(dict(CONV, live_population=4096), 5, 5.0, 8192)
+    r = Recorder()
+    harness.warm_up(r, s, np.zeros((s.n_convs + 8200, 12, 1)), 32, 4)
+    # fillers first, b x 4 at a time: the router pins new conversations
+    # in turn, so each replica gets one wave of b
+    assert r.drains[:6] == [4, 8, 16, 32, 64, 128]
+    assert set(r.drains[6:-1]) == {128}
+    assert all(c.startswith("f") for c in r.sent[:252])

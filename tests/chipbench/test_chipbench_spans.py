@@ -22,8 +22,8 @@ def span(i, name, wave, start_ms, end_ms, parent=-1):
 
 
 def obs(records=(), trace=None):
-    return Observations({"p": 16384, "d": 768}, list(records), [], [], 0.0,
-                        {}, trace)
+    return Observations({"p": 16384, "d": 768}, list(records),
+                        [0] * len(records), [], [], 0.0, {}, trace)
 
 
 def test_the_clock_offset_is_recovered_from_a_shifted_trace():
@@ -110,6 +110,16 @@ def test_the_readers_read_what_a_run_kept():
 
 def test_the_gate_metric_file_reads_the_records():
     o = obs([rec(0, True), rec(1, False), rec(1, False)])
+    assert registry.reader("gate_open_share")(o) == pytest.approx(50.0)
+
+
+def test_each_replica_numbers_its_own_waves():
+    # wave 0 of replica 0 opened its gate, wave 0 of replica 1 did not:
+    # two waves, one open, not one wave
+    o = obs([rec(0, True), rec(0, False), rec(1, False), rec(1, False)])
+    o.replica = [0, 1, 0, 1]
+    assert registry.reader("gate_open_share")(o) == pytest.approx(25.0)
+    o.replica = [0] * 4
     assert registry.reader("gate_open_share")(o) == pytest.approx(50.0)
 
 

@@ -22,7 +22,7 @@ def synthetic():
             ("jit__scatter_slab(9)", 8 * ms, 1 * ms)]
     host = {"pump": [("PjitFunction(step_batch)", 3.5 * ms, 1 * ms),
                      ("wait", 3 * ms, 5 * ms)]}
-    return {"device": {tracing.OPS_LINE: ops, tracing.MODULES_LINE: mods},
+    return {"chips": [{tracing.OPS_LINE: ops, tracing.MODULES_LINE: mods}],
             "host": host}
 
 
@@ -48,7 +48,7 @@ def test_reduce_on_a_synthetic_trace():
 
 def test_a_trace_without_device_operations_is_an_error():
     with pytest.raises(ValueError):
-        tracing.reduce({"device": {}, "host": {}})
+        tracing.reduce({"chips": [{}], "host": {}})
 
 
 def test_module_names_drop_their_id():
@@ -57,8 +57,10 @@ def test_module_names_drop_their_id():
 
 
 def recorded():
+    """The recorded trace as ``tracing.events`` gives it: one chip."""
     with gzip.open(RECORDED, "rt") as f:
-        return json.load(f)["events"]
+        ev = json.load(f)["events"]
+    return {"chips": [ev["device"]], "host": ev["host"]}
 
 
 def test_reduce_on_a_recorded_chip_trace():
@@ -85,12 +87,76 @@ def test_metrics_from_the_recorded_trace():
     r = tracing.reduce(recorded())
     rec = [NS(list_dists=4000, conv_id="c", turn=1, queue_wait_s=0.0,
               centroid_dists=1024, code_dists=0)] * 128
-    o = Observations({"p": 16384, "d": 768}, rec, [], [], 0.0,
+    o = Observations({"p": 16384, "d": 768}, rec, [0] * len(rec), [], [], 0.0,
                      {"hbm_bytes_per_s": 819e9}, r)
     o.traced_records = rec
     assert 0 <= rd.device_idle_share(o) < 100
     assert rd.step_device_ms(o) == pytest.approx(
         1e3 * sum(r["modules"]["jit_step_batch"]) / 4)
     assert 0 < rd.step_roofline(o) < 100
-    assert rd.gather_device_ms(o) == pytest.approx(
-        1e3 * sum(r["modules"]["jit_gather"]) / 4)
+
+
+def test_each_chips_plane_is_reduced_on_its_own():
+    """Two device planes against one host plane: one entry per chip,
+    the first equal to the one-plane reduction, which stands at the top
+    level as before."""
+    ev = recorded()
+    (first,) = ev["chips"]
+    ops = first[tracing.OPS_LINE]
+    # a second chip busy with every other operation of the first
+    steps = [m for m in first[tracing.MODULES_LINE]
+             if m[0].startswith("jit_step_batch")]
+    other = {tracing.OPS_LINE: ops[::2], tracing.MODULES_LINE: steps[:2]}
+    one = tracing.reduce(ev, kernels=("pq_adc",))
+    two = tracing.reduce(dict(ev, chips=[first, other]),
+                         kernels=("pq_adc",))
+    assert len(one["chips"]) == 1 and len(two["chips"]) == 2
+    alone = {k: v for k, v in one.items() if k != "chips"}
+    assert one["chips"][0] == alone == two["chips"][0]
+    assert {k: v for k, v in two.items() if k != "chips"} == alone
+    second = two["chips"][1]
+    assert 0 < second["busy_s"] < alone["busy_s"]
+    assert second["window_s"] <= alone["window_s"]
+    assert len(second["modules"]["jit_step_batch"]) == 2
+
+
+def test_readers_take_every_chips_steps_and_idle_share():
+    """Two chips: the step readers pool both chips' ``step_batch``
+    executions against all traced turns, the idle share is the chips'
+    mean; one chip reads as its own plane."""
+    from types import SimpleNamespace as NS
+    from chipbench.harness import Observations
+    ev = recorded()
+    (first,) = ev["chips"]
+    steps = [m for m in first[tracing.MODULES_LINE]
+             if m[0].startswith("jit_step_batch")]
+    other = {tracing.OPS_LINE: first[tracing.OPS_LINE][::2],
+             tracing.MODULES_LINE: steps[:2]}
+    one = tracing.reduce(ev)
+    two = tracing.reduce(dict(ev, chips=[first, other]))
+    rec = [NS(list_dists=4000, centroid_dists=1024)] * 128
+
+    def observed(r):
+        o = Observations({"p": 16384, "d": 768}, rec, [0] * 64 + [1] * 64,
+                         [], [], 0.0, {"hbm_bytes_per_s": 819e9}, r)
+        o.traced_records = rec
+        return o
+
+    pooled = one["modules"]["jit_step_batch"] + [d * 1e-9 for _, _, d in
+                                                 steps[:2]]
+    assert rd.step_device_ms(observed(two)) == pytest.approx(
+        1e3 * sum(pooled) / 6)
+    assert rd.step_roofline(observed(two)) == pytest.approx(
+        rd.step_roofline(observed(one)) * sum(pooled[:4]) / sum(pooled))
+    idle = [1 - c["busy_s"] / c["window_s"] for c in two["chips"]]
+    assert rd.device_idle_share(observed(two)) == pytest.approx(
+        50.0 * sum(idle))
+    assert rd.device_idle_share(observed(one)) == (
+        100.0 * (1.0 - one["busy_s"] / one["window_s"]))
+
+
+@pytest.mark.parametrize("name, want", [("/device:TPU:0", 0),
+                                        ("/device:TPU:3", 3),
+                                        ("/device:TPU:12 extra", 12)])
+def test_device_planes_sort_by_their_id(name, want):
+    assert tracing._device_id(name) == want
